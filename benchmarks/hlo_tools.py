@@ -12,7 +12,10 @@ were localized.
 """
 
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+# A CPU compile tool, like repro.launch.dryrun: host platform, 512 devices.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 
 import argparse
 import collections
@@ -85,14 +88,14 @@ def main():
     import jax
     from repro.configs import registry
     from repro.launch.dryrun import build_combo
-    from repro.launch.mesh import make_production_mesh, mesh_context
+    from repro.launch.mesh import make_production_mesh
 
     pat = len(registry.get_config(args.arch).block_pattern)
     layers = args.layers or pat
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     fn, fargs = build_combo(args.arch, args.shape, mesh, args.buffer_mode, None,
                             dict(num_layers=layers, unroll=True))
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         txt = fn.lower(*fargs).compile().as_text()
 
     print(f"== op histogram (result bytes, {layers}-layer probe) ==")

@@ -1,6 +1,6 @@
 """Kernel hot-spot benchmark — the fused buffered-KD loss.
 
-On CPU the Pallas kernels run in interpret mode (Python), so wall-clock
+Off the TPU the Pallas kernels run in interpret mode (Python), so wall-clock
 favors the jnp reference; the meaningful numbers here are (a) correctness
 parity at benchmark scale and (b) the analytic HBM-traffic model that
 motivates the fusion (reported as derived columns):
@@ -41,7 +41,7 @@ def main(rows=256, vocab=8192, verbose=True):
         y, s_, jax.lax.stop_gradient(t), jax.lax.stop_gradient(b), tau)))
     us_ref = bench(grad_ref, s)
     parity = float(jnp.max(jnp.abs(
-        ops.kd_loss(y, s, t, b, tau, use_pallas=True, interpret=True)
+        ops.kd_loss(y, s, t, b, tau, use_pallas=True)
         - ref.kd_loss_mean_ref(y, s, t, b, tau))))
 
     # Derived HBM traffic (bytes) per backward step at fp32.
@@ -59,7 +59,7 @@ def main(rows=256, vocab=8192, verbose=True):
     bb = jax.random.normal(ks[1], (8, 512, 256))
     us_rg = bench(jax.jit(ref.rglru_ref), a, bb)
     err = float(jnp.max(jnp.abs(
-        ops.rglru(a, bb, use_pallas=True, interpret=True) - ref.rglru_ref(a, bb))))
+        ops.rglru(a, bb, use_pallas=True) - ref.rglru_ref(a, bb))))
     print(f"rglru_ref_scan,{us_rg:.0f},shape=8x512x256")
     print(f"rglru_kernel,0,parity_maxerr={err:.2e}")
 
@@ -69,7 +69,7 @@ def main(rows=256, vocab=8192, verbose=True):
     B = jax.random.normal(ks[3], (2, 512, 1, 64)) * 0.5
     C = jax.random.normal(ks[0], (2, 512, 1, 64)) * 0.5
     us_ssd = bench(jax.jit(lambda *a_: ref.ssd_ref(*a_, 128)[0]), x, dt, A, B, C)
-    yk, _ = ops.ssd(x, dt, A, B, C, 128, use_pallas=True, interpret=True)
+    yk, _ = ops.ssd(x, dt, A, B, C, 128, use_pallas=True)
     yr, _ = ref.ssd_ref(x, dt, A, B, C, 128)
     err = float(jnp.max(jnp.abs(yk - yr)))
     print(f"ssd_ref_chunked,{us_ssd:.0f},shape=2x512x8x64")
@@ -78,4 +78,6 @@ def main(rows=256, vocab=8192, verbose=True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -40,7 +40,7 @@ import numpy as np
 from repro.configs import registry
 from repro.core.fl import FederatedKD, FLConfig
 from repro.core.simulator import EventDrivenSimulator
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.launch.serve import summarize
 from repro.live import LiveSystem, LiveTrainer, lm_adapter, lm_fl_data, nll_on
 from repro.serve import ServeEngine, build_stream
@@ -121,7 +121,7 @@ def main():
                          "backend": jax.default_backend()},
               "streams": {}}
     ok = True
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         # One pretrained core is the shared starting point: the serve-only
         # baseline serves it frozen, every co-run starts from it.
         w0_trainer = build_trainer(cfg, flcfg, data, "bkd")
@@ -184,4 +184,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -49,7 +49,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.launch.serve import summarize
 from repro.models.transformer import Transformer
 from repro.serve import STREAMS, ServeEngine, build_stream
@@ -66,7 +66,7 @@ def run_legacy(cfg, params, reqs, slots, max_len, mesh):
 
 def run_engine(cfg, params, reqs, slots, max_len, mesh, engine=None):
     t0 = time.perf_counter()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         # construct/reset inside the mesh context: the engine's jitted
         # state init matches the step outputs' shardings only under the
         # same mesh (keeps every executable compiled exactly once)
@@ -95,7 +95,7 @@ def paged_section(cfg, params, mesh, args, n_req):
     slots, ps = args.slots, args.page_size
     max_len = 4 * args.max_len
     short_max = max(4, ps - 2)     # prompts below one page: the skew
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         dense = ServeEngine(cfg, params, slots=slots, max_len=max_len)
         paged = ServeEngine(cfg, params, slots=slots, max_len=max_len,
                             paged=True, page_size=ps)
@@ -180,7 +180,7 @@ def main():
     cfg = registry.get_config(args.arch) if args.full \
         else registry.get_smoke_config(args.arch)
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(args.seed))
 
     def stream(name):
@@ -243,4 +243,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

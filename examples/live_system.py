@@ -22,7 +22,7 @@ import jax
 from repro.configs import registry
 from repro.core.fl import FederatedKD, FLConfig
 from repro.core.simulator import EventDrivenSimulator
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.launch.serve import summarize
 from repro.live import LiveSystem, LiveTrainer, lm_adapter, lm_fl_data, nll_on
 from repro.serve import STREAMS, ServeEngine, build_stream
@@ -56,7 +56,7 @@ def main():
                         seed=args.seed, prompt_max=10, out_max=4)
 
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fl = FederatedKD(lm_adapter(cfg), flcfg, core, edges, test,
                          scheduler=EventDrivenSimulator(
                              flcfg.num_edges, "uniform", seed=args.seed))

@@ -22,7 +22,7 @@ from repro.configs import registry
 from repro.core import distill
 from repro.data import make_token_stream
 from repro.launch import steps as St
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.models.transformer import Transformer
 from repro.optim import adamw
 
@@ -65,7 +65,7 @@ def main():
             params, st, m = pre(params, st, batch, jnp.int32(i))
         return params
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         core, _ = Transformer.init(cfg, jax.random.key(0))
         core = run_phase(core, core_silo, args.steps, 1)         # Phase 0
         teacher = run_phase(jax.tree.map(jnp.copy, core),

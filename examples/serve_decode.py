@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import registry
 from repro.launch import steps as St
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.models.transformer import Transformer
 
 
@@ -35,7 +35,7 @@ def main():
     mesh = make_test_mesh()
     max_len = args.prompt_len + args.new_tokens + 1
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(0))
         prompt = jax.random.randint(jax.random.key(1),
                                     (args.batch, args.prompt_len), 0,

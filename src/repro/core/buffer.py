@@ -30,17 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-#: Floor on the tail probability mass of a top-k compressed cache entry.
-#: The tail mass is computed as ``1 - exp(top_lse - full_lse)``; when the
-#: top-k entries hold essentially all the mass, ``top_lse`` and ``full_lse``
-#: agree to within float32 machine epsilon (~1.2e-7) and the subtraction
-#: cancels to exactly 0, which would put ``log(0) = -inf`` into the cache
-#: and poison every loss that reads it.  Flooring the mass at 1e-9 — below
-#: the smallest tail mass float32 cancellation can even represent — bounds
-#: the tail logsumexp at ``full_lse + ln(1e-9) ~= full_lse - 20.7``: far
-#: enough below every retained top-k logit that the reconstructed softmax
-#: treats the tail as negligible, yet finite in value and gradient.
-TAIL_MASS_FLOOR = 1e-9
+from repro.core.distill import tail_logsumexp
 
 #: adapter.logits -> jitted (state, x) -> logits batch forward.  One entry
 #: per adapter, so every `core_logits` call over same-shaped batches reuses
@@ -115,13 +105,8 @@ def precompute_logits(adapter, state, ds, batch=512, topk=None):
     # log(0) and the compressed form pointless (use the exact cache then).
     topk = min(topk, logits.shape[-1] - 1)
     tv, ti = jax.lax.top_k(logits, topk)
-    full_lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    top_lse = jax.scipy.special.logsumexp(tv, axis=-1)
-    # tail lse: log(exp(full) - exp(top)) computed stably; see TAIL_MASS_FLOOR
-    # for why the mass is floored before the log.
-    diff = jnp.exp(jnp.minimum(top_lse - full_lse, 0.0))
-    tail = full_lse + jnp.log(jnp.maximum(1.0 - diff, TAIL_MASS_FLOOR))
-    return LogitCache(top_vals=tv, top_idx=ti, tail_lse=tail)
+    return LogitCache(top_vals=tv, top_idx=ti,
+                      tail_lse=tail_logsumexp(logits, ti))
 
 
 def reconstruct_logits(cache_entry, vocab):

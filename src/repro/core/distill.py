@@ -33,6 +33,17 @@ def _mask_pad(logits, vocab):
     return logits
 
 
+def tail_logsumexp(logits, top_idx):
+    """logsumexp of ``logits`` over the entries ``top_idx`` does not name.
+
+    Taken over those entries directly: ``log(1 - exp(top - full))`` cancels
+    to zero in float32 once the top entries hold nearly all the mass."""
+    in_top = jnp.put_along_axis(jnp.zeros(logits.shape, jnp.bool_), top_idx,
+                                True, axis=-1, inplace=False)
+    return jax.scipy.special.logsumexp(jnp.where(in_top, NEG_INF, logits),
+                                       axis=-1)
+
+
 def ce_loss(logits, labels, *, vocab=None, mask=None):
     """Cross entropy, mean over (optionally masked) examples.
     logits: (..., V); labels: (...) int."""
@@ -176,7 +187,7 @@ def topk_kl_cached(student_logits, top_vals, top_idx, tail_lse, tau,
     lp_top = tv - z[..., None]
     lp_tail = tl - z
     ls_top = jnp.take_along_axis(ls, top_idx, axis=-1)
-    ms_tail = jnp.log(jnp.maximum(1.0 - jnp.exp(ls_top).sum(-1), 1e-9))
+    ms_tail = tail_logsumexp(ls, top_idx)      # log of the student tail mass
     kl = (jnp.sum(jnp.exp(lp_top) * (lp_top - ls_top), axis=-1)
           + jnp.exp(lp_tail) * (lp_tail - ms_tail)) * (tau ** 2)
     if mask is not None:

@@ -147,14 +147,12 @@ def kd_terms(ctx: MethodContext, lg, tls, bl, y):
     tau = ctx.cfg.tau
     if ctx.backend == "pallas":
         from repro.kernels import ops
-        interpret = jax.default_backend() != "tpu"
         if tls.shape[0] == 1:
             t_eff = tls[0]
         else:
             af = distill.ensemble_probs(tls, tau)
             t_eff = tau * jnp.log(jnp.maximum(af, 1e-30))
-        return ops.kd_loss(y, lg, t_eff, bl, tau, use_pallas=True,
-                           interpret=interpret)
+        return ops.kd_loss(y, lg, t_eff, bl, tau, use_pallas=True)
     loss = distill.l_kd(lg, tls, y, tau)
     if bl is not None:
         loss = loss + distill.kl_soft(lg, bl, tau)

@@ -22,7 +22,7 @@ parity test asserts exact equality) while compiling once per shape instead
 of once per edge per round, and executing one batched matmul stream the
 backend can fuse — wall-clock becomes sub-linear in R.
 
-When a mesh is active (``jax.set_mesh`` / ``with mesh:``), the stacked edge
+When a mesh is active (``jax.set_mesh``), the stacked edge
 axis is sharded over the mesh's data axes via the ``repro.sharding`` logical
 "batch" rule, so a multi-host mesh splits the edge population across hosts
 (``shard_map`` over the edge axis; each shard runs the same vmapped scan).
@@ -31,7 +31,6 @@ axis is sharded over the mesh's data axes via the ``repro.sharding`` logical
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Optional
 
 import jax
@@ -43,11 +42,6 @@ from repro.data.pipeline import batches
 from repro.optim import sgd_momentum, step_decay
 from repro.sharding.rules import (DEFAULT_RULES, get_abstract_mesh_or_none,
                                   logical_to_spec)
-
-try:
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:  # pragma: no cover - older/newer jax layouts
-    _shard_map = None
 
 
 def stack_trees(trees):
@@ -172,33 +166,24 @@ def make_edge_trainer(adapter, lr, weight_decay, loss_fn=None):
 
     def train(stacked_states, x, y, idx, valid, boundaries):
         mesh = get_abstract_mesh_or_none()
-        if mesh is not None and _shard_map is not None:
+        if mesh is not None:
             # Shard the edge axis over the mesh's data axes (logical "batch"
-            # rule); within each shard the same vmapped scan runs.
-            try:
-                spec = logical_to_spec(("batch",), (x.shape[0],), mesh,
-                                       DEFAULT_RULES)
-            except (TypeError, ValueError):
-                spec = None  # no divisible data axis for this mesh shape
-            if spec is not None and spec[0] is not None:
+            # rule); within each shard the same vmapped scan runs.  An edge
+            # count no data axis divides stays replicated (spec None).
+            spec = logical_to_spec(("batch",), (x.shape[0],), mesh,
+                                   DEFAULT_RULES)
+            if spec[0] is not None:
                 # Key on the mesh object itself (Mesh/AbstractMesh are
                 # hashable): keeps the executable bound to ITS mesh and
                 # avoids id-reuse collisions after garbage collection.
                 key = (mesh, spec)
-                try:
-                    if key not in shard_cache:
-                        in_spec = P(spec[0])
-                        shard_cache[key] = jax.jit(_shard_map(
-                            vmapped, mesh=mesh, in_specs=(in_spec,) * 6,
-                            out_specs=in_spec, check_rep=False))
-                    return shard_cache[key](stacked_states, x, y, idx, valid,
-                                            boundaries)
-                except (TypeError, ValueError) as e:
-                    # Trace-time incompatibility (e.g. abstract-only mesh on
-                    # this jax version): fall back to the replicated vmap.
-                    # Runtime errors propagate — they are real failures.
-                    warnings.warn(f"edge-axis shard_map unavailable "
-                                  f"({e}); running replicated")
+                if key not in shard_cache:
+                    in_spec = P(spec[0])
+                    shard_cache[key] = jax.jit(jax.shard_map(
+                        vmapped, mesh=mesh, in_specs=(in_spec,) * 6,
+                        out_specs=in_spec, check_vma=False))
+                return shard_cache[key](stacked_states, x, y, idx, valid,
+                                        boundaries)
         return jit_vmapped(stacked_states, x, y, idx, valid, boundaries)
 
     return train

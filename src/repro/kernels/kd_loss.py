@@ -23,7 +23,11 @@ re-reading the logits once more (two total passes, matching flash-attention
 economics; the jnp reference needs >= 6 full-tensor passes and a live
 softmax).  Teachers/buffer are frozen in Phase 2 so they get no gradient.
 
-Block shapes: rows_block x vocab_tile, vocab_tile a multiple of 128 lanes.
+Block shapes: rows_block x vocab_tile, vocab_tile a multiple of 128 lanes
+and rows_block a multiple of 32 sublanes (int8/bf16/f32 all tile legally)
+sized so one f32 tile stays within ``_TILE_ELEMS``.  Per-row operands
+(labels, upstream grad, dequant scale/zero) enter as ``(rows, 1)`` columns:
+a rank-1 block of rows_block would need rows_block % 128 == 0 on TPU.
 Grid is (row_blocks, vocab_blocks) with vocab innermost; VMEM scratch
 carries the online stats across vocab tiles of one row block.
 
@@ -91,8 +95,8 @@ def _fwd_body(labels_ref, s, t, b, stats_ref, acc_ref, *, tau, vocab_tile,
 
     rows = s.shape[0]
     cols = v_idx * vocab_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    y = labels_ref[...]                                   # (rows,)
-    hit = (cols == y[:, None]).astype(jnp.float32)
+    y = labels_ref[...]                                   # (rows, 1)
+    hit = (cols == y).astype(jnp.float32)
     s_y = nums_a[:, 0:1] + jnp.sum(s * hit, axis=-1, keepdims=True)
 
     m_s, d_s, _ = _online_update(maxes[:, 0:1], denoms[:, 0:1], [], s, [])
@@ -154,8 +158,7 @@ def _fwd_kernel(labels_ref, s_ref, t_ref, b_ref, stats_ref,
 def _dequant_tile(codes_ref, scale_ref, zero_ref, v_idx, vocab_tile, vocab):
     """Reconstruct a teacher tile from int8 codes + per-row (scale, zero),
     masking padded vocab columns to NEG (codes can't encode the sentinel)."""
-    t = (codes_ref[...].astype(jnp.float32) * scale_ref[...][:, None]
-         + zero_ref[...][:, None])
+    t = codes_ref[...].astype(jnp.float32) * scale_ref[...] + zero_ref[...]
     cols = v_idx * vocab_tile + jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
     return jnp.where(cols < vocab, t, NEG)
 
@@ -180,13 +183,13 @@ def _bwd_body(labels_ref, g_ref, stats_ref, s, t, b, ds_ref, *, tau,
     lse_s = stats[:, 0:1]
     lse_st = stats[:, 2:3]
     lse_tt = stats[:, 3:4]
-    g = g_ref[...][:, None] * mean_scale                    # (rows, 1)
+    g = g_ref[...] * mean_scale                             # (rows, 1)
 
     p_s = jnp.exp(s - lse_s)
     p_st = jnp.exp(s / tau - lse_st)
     p_tt = jnp.exp(t / tau - lse_tt)
     cols = v_idx * vocab_tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    onehot = (cols == labels_ref[...][:, None]).astype(jnp.float32)
+    onehot = (cols == labels_ref[...]).astype(jnp.float32)
 
     ds = p_s - onehot + tau * (p_st - p_tt)
     if with_buffer:
@@ -219,19 +222,25 @@ def _quant_bwd_kernel(labels_ref, g_ref, stats_ref, s_ref, codes_ref,
               mean_scale=mean_scale)
 
 
-def _row_block(rows):
-    for cand in (16, 8, 4, 2, 1):
-        if rows % cand == 0:
-            return cand
-    return 1
+# Largest f32 tile (rows_block * vocab_tile elements) a grid step holds:
+# 256 KiB, so the double-buffered inputs plus the kernel's tile-sized f32
+# temporaries stay well inside the default scoped VMEM.
+_TILE_ELEMS = 1 << 16
 
 
-def _row_block_q(rows):
-    # int8 operands want (32, 128) min tiles on TPU — prefer 32 rows.
-    for cand in (32, 16, 8, 4, 2, 1):
-        if rows % cand == 0:
+def _row_block(rows, vt):
+    """Rows per block: a multiple of 32 (int8 codes tile at (32, 128)) or
+    of 8 that divides ``rows`` and keeps the tile within ``_TILE_ELEMS``;
+    the whole row extent (always a legal block) when none divides."""
+    for cand in (512, 256, 128, 64, 32, 16, 8):
+        if cand * vt <= _TILE_ELEMS and rows % cand == 0:
             return cand
-    return 1
+    return rows
+
+
+def _col(a, dtype):
+    """A per-row vector as the (rows, 1) column the kernels block over."""
+    return a.astype(dtype).reshape(-1, 1)
 
 
 def _vocab_tile(v):
@@ -244,8 +253,8 @@ def _vocab_tile(v):
 def kd_stats_fwd(labels, s, t, b, tau, *, interpret=False):
     """Returns stats (rows, N_STATS).  b may be None (plain KD)."""
     rows, v = s.shape
-    rb = _row_block(rows)
     vt = _vocab_tile(v)
+    rb = _row_block(rows, vt)
     with_buffer = b is not None
     if b is None:
         b = s  # dummy operand (ignored by the kernel)
@@ -256,7 +265,7 @@ def kd_stats_fwd(labels, s, t, b, tau, *, interpret=False):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
@@ -265,13 +274,13 @@ def kd_stats_fwd(labels, s, t, b, tau, *, interpret=False):
         out_shape=jax.ShapeDtypeStruct((rows, N_STATS), jnp.float32),
         scratch_shapes=[pltpu.VMEM((4, rb, 8), jnp.float32)],
         interpret=interpret,
-    )(labels, s, t, b)
+    )(_col(labels, jnp.int32), s, t, b)
 
 
 def kd_grad_bwd(labels, g, stats, s, t, b, tau, mean_scale, *, interpret=False):
     rows, v = s.shape
-    rb = _row_block(rows)
     vt = _vocab_tile(v)
+    rb = _row_block(rows, vt)
     with_buffer = b is not None
     if b is None:
         b = s
@@ -282,8 +291,8 @@ def kd_grad_bwd(labels, g, stats, s, t, b, tau, mean_scale, *, interpret=False):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, N_STATS), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
@@ -292,7 +301,7 @@ def kd_grad_bwd(labels, g, stats, s, t, b, tau, mean_scale, *, interpret=False):
         out_specs=pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, v), s.dtype),
         interpret=interpret,
-    )(labels, g, stats, s, t, b)
+    )(_col(labels, jnp.int32), _col(g, jnp.float32), stats, s, t, b)
 
 
 def kd_quant_stats_fwd(labels, s, codes, scale, zero, b, tau, vocab, *,
@@ -301,8 +310,8 @@ def kd_quant_stats_fwd(labels, s, codes, scale, zero, b, tau, vocab, *,
     per-row (scale, zero).  ``vocab`` is the true (pre-padding) vocab size;
     padded code columns are masked to NEG by column index.  b may be None."""
     rows, v = s.shape
-    rb = _row_block_q(rows)
     vt = _vocab_tile(v)
+    rb = _row_block(rows, vt)
     with_buffer = b is not None
     if b is None:
         b = s  # dummy operand (ignored by the kernel)
@@ -314,25 +323,26 @@ def kd_quant_stats_fwd(labels, s, codes, scale, zero, b, tau, vocab, *,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((rb, N_STATS), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, N_STATS), jnp.float32),
         scratch_shapes=[pltpu.VMEM((4, rb, 8), jnp.float32)],
         interpret=interpret,
-    )(labels, s, codes, scale, zero, b)
+    )(_col(labels, jnp.int32), s, codes, _col(scale, jnp.float32),
+      _col(zero, jnp.float32), b)
 
 
 def kd_quant_grad_bwd(labels, g, stats, s, codes, scale, zero, b, tau, vocab,
                       mean_scale, *, interpret=False):
     rows, v = s.shape
-    rb = _row_block_q(rows)
     vt = _vocab_tile(v)
+    rb = _row_block(rows, vt)
     with_buffer = b is not None
     if b is None:
         b = s
@@ -345,19 +355,20 @@ def kd_quant_grad_bwd(labels, g, stats, s, codes, scale, zero, b, tau, vocab,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, N_STATS), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
-            pl.BlockSpec((rb,), lambda i, j: (i,)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((rb, vt), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, v), s.dtype),
         interpret=interpret,
-    )(labels, g, stats, s, codes, scale, zero, b)
+    )(_col(labels, jnp.int32), _col(g, jnp.float32), stats, s, codes,
+      _col(scale, jnp.float32), _col(zero, jnp.float32), b)
 
 
 def assemble_loss(stats, tau, with_buffer):

@@ -3,7 +3,9 @@
 `use_pallas` selects the kernel path; the default is chosen by backend
 (kernels on TPU, jnp reference on CPU so the multi-pod dry-run lowers with
 stock XLA ops).  `interpret=True` runs the kernel bodies in Python on CPU —
-that is how the test-suite validates them against ref.py.
+that is how the test-suite validates them against ref.py.  Left at None,
+`interpret` follows the backend too: compiled kernels on TPU, never the
+interpreter there.
 """
 
 from __future__ import annotations
@@ -12,15 +14,47 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.kernels import kd_loss as _kd
 from repro.kernels import ref as _ref
 from repro.kernels.rglru import rglru_pallas
 from repro.kernels.ssd import ssd_pallas
+from repro.sharding.rules import get_abstract_mesh_or_none, logical_to_spec
 
 
 def default_use_pallas():
     return jax.default_backend() == "tpu"
+
+
+def _interpret(interpret):
+    """Interpret mode (kernel bodies run in Python) everywhere but the TPU,
+    unless the caller chose."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def _over_rows(fn, *args):
+    """``fn(*args)``, a mean over rows, for row-major ``args``.
+
+    Mosaic kernels cannot be partitioned automatically, so under a mesh
+    the call runs in a ``shard_map``: the rows split over every mesh axis
+    that divides them, each device runs the kernel on whole rows (the full
+    vocab) of its shard, and the equal-sized shard means are averaged."""
+    mesh = get_abstract_mesh_or_none()
+    axes, n = (), 1
+    if mesh is not None:
+        rows = args[0].shape[0]
+        for name, size, kind in zip(mesh.axis_names, mesh.axis_sizes,
+                                    mesh.axis_types):
+            if kind == AxisType.Auto and size > 1 and rows % (n * size) == 0:
+                axes, n = axes + (name,), n * size
+    if not axes:
+        return fn(*args)
+    spec = P(axes)
+    means = jax.shard_map(lambda *a: fn(*a)[None], mesh=mesh,
+                          in_specs=(spec,) * len(args), out_specs=spec,
+                          check_vma=False)(*args)
+    return jnp.mean(means)
 
 
 # ---------------------------------------------------------------------------
@@ -56,7 +90,7 @@ _kd_loss_pallas.defvjp(_kd_fwd, _kd_bwd)
 
 
 def kd_loss(labels, student_logits, teacher_logits, buffer_logits=None, tau=2.0,
-            *, use_pallas=None, interpret=False):
+            *, use_pallas=None, interpret=None):
     """Mean buffered-KD loss over rows.  Differentiable w.r.t. student logits.
     Shapes: labels (R,), logits (R, V).  Vocabularies that are not a
     multiple of the kernel's 128-lane tile are padded with NEG_INF columns
@@ -74,8 +108,11 @@ def kd_loss(labels, student_logits, teacher_logits, buffer_logits=None, tau=2.0,
             if buffer_logits is not None:
                 buffer_logits = _pad(buffer_logits)
         b = buffer_logits if buffer_logits is not None else student_logits
-        return _kd_loss_pallas(labels, student_logits, teacher_logits, b,
-                               float(tau), buffer_logits is not None, interpret)
+        kernel = functools.partial(
+            _kd_loss_pallas, tau=float(tau),
+            with_buffer=buffer_logits is not None,
+            interpret=_interpret(interpret))
+        return _over_rows(kernel, labels, student_logits, teacher_logits, b)
     t = jax.lax.stop_gradient(teacher_logits)
     b = jax.lax.stop_gradient(buffer_logits) if buffer_logits is not None else None
     return _ref.kd_loss_mean_ref(labels, student_logits, t, b, tau)
@@ -122,7 +159,7 @@ _kd_loss_quant_pallas.defvjp(_kd_quant_fwd, _kd_quant_bwd)
 
 def kd_loss_quant(labels, student_logits, codes, scale, zero,
                   buffer_logits=None, tau=2.0, *, use_pallas=None,
-                  interpret=False):
+                  interpret=None):
     """Mean buffered-KD loss with the teacher given as per-row affine
     quantization payload: ``teacher = codes * scale[:, None] + zero[:, None]``
     (int8 codes — the int4 codec ships nibble-packed bytes and unpacks its
@@ -148,9 +185,12 @@ def kd_loss_quant(labels, student_logits, codes, scale, zero,
             if buffer_logits is not None:
                 buffer_logits = _pad(buffer_logits, -1e30)
         b = buffer_logits if buffer_logits is not None else student_logits
-        return _kd_loss_quant_pallas(labels, student_logits, codes, scale,
-                                     zero, b, float(tau),
-                                     buffer_logits is not None, v, interpret)
+        kernel = functools.partial(
+            _kd_loss_quant_pallas, tau=float(tau),
+            with_buffer=buffer_logits is not None, vocab=v,
+            interpret=_interpret(interpret))
+        return _over_rows(kernel, labels, student_logits, codes, scale, zero,
+                          b)
     t = jax.lax.stop_gradient(codes.astype(jnp.float32) * scale[:, None]
                               + zero[:, None])
     b = (jax.lax.stop_gradient(buffer_logits)
@@ -162,11 +202,11 @@ def kd_loss_quant(labels, student_logits, codes, scale, zero,
 # RG-LRU scan.
 # ---------------------------------------------------------------------------
 
-def rglru(a, b, *, use_pallas=None, interpret=False):
+def rglru(a, b, *, use_pallas=None, interpret=None):
     if use_pallas is None:
         use_pallas = default_use_pallas()
     if use_pallas:
-        return rglru_pallas(a, b, interpret=interpret)
+        return rglru_pallas(a, b, interpret=_interpret(interpret))
     return _ref.rglru_ref(a, b)
 
 
@@ -174,7 +214,7 @@ def rglru(a, b, *, use_pallas=None, interpret=False):
 # SSD chunk scan (B/C broadcast to heads before the kernel).
 # ---------------------------------------------------------------------------
 
-def ssd(x, dt, A, B, C, chunk, *, use_pallas=None, interpret=False):
+def ssd(x, dt, A, B, C, chunk, *, use_pallas=None, interpret=None):
     if use_pallas is None:
         use_pallas = default_use_pallas()
     h = x.shape[2]
@@ -183,7 +223,8 @@ def ssd(x, dt, A, B, C, chunk, *, use_pallas=None, interpret=False):
         Bh = jnp.repeat(B, h // g, axis=2)
         Ch = jnp.repeat(C, h // g, axis=2)
         return ssd_pallas(x.astype(jnp.float32), dt, A, Bh.astype(jnp.float32),
-                          Ch.astype(jnp.float32), chunk, interpret=interpret)
+                          Ch.astype(jnp.float32), chunk,
+                          interpret=_interpret(interpret))
     return _ref.ssd_ref(x.astype(jnp.float32), dt, A, B.astype(jnp.float32),
                         C.astype(jnp.float32), chunk)
 
@@ -193,27 +234,51 @@ def ssd(x, dt, A, B, C, chunk, *, use_pallas=None, interpret=False):
 # ---------------------------------------------------------------------------
 
 def swa_decode_attn(q, k_cache, v_cache, pos, *, window=None, ring=False,
-                    use_pallas=None, interpret=False):
+                    use_pallas=None, interpret=None):
     if use_pallas is None:
         use_pallas = default_use_pallas()
     if use_pallas:
         from repro.kernels.swa_decode import swa_decode
         return swa_decode(q, k_cache, v_cache, pos, window=window, ring=ring,
-                          interpret=interpret)
+                          interpret=_interpret(interpret))
     return _ref.swa_decode_ref(q, k_cache, v_cache, pos, window=window, ring=ring)
 
 
-def paged_decode_attn(q, k_pool, v_pool, pt, pos, *, window=None,
-                      use_pallas=None, interpret=False):
+def paged_decode_attn(q, k_pool, v_pool, pt, pos, *, layer=None, window=None,
+                      use_pallas=None, interpret=None):
     """Block-paged decode attention (the paged ServeEngine's tick hot spot).
-    q: (B, N, G, D); k/v_pool: (P, page_size, N, D); pt: (B, PP) int32 page
-    table; pos: (B,) int32.  The pallas path gathers pages inside the
-    kernel's index maps (scalar-prefetched page table); the reference path
-    materializes the dense per-slot view."""
+    q: (B, N, G, D); k/v_pool: (P, page_size, N*D), or (L, P, page_size,
+    N*D) stacked over layers with ``layer`` picking one; pt: (B, PP) int32
+    page table; pos: (B,) int32.  The pallas path gathers pages inside the
+    kernel's index maps (scalar-prefetched page table and layer), reading
+    the pool in place; the reference path materializes the dense per-slot
+    view.
+
+    Under a mesh the kernel runs in a ``shard_map``: Mosaic kernels are not
+    auto-partitioned, and XLA would otherwise gather the whole pool onto
+    every device.  Slots split over the axes that carry the batch, kv heads
+    over those that carry them, as the model's sharding rules lay out q;
+    every device keeps all pages of its heads."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     if use_pallas is None:
         use_pallas = default_use_pallas()
-    if use_pallas:
-        from repro.kernels.swa_decode import paged_decode
-        return paged_decode(q, k_pool, v_pool, pt, pos, window=window,
-                            interpret=interpret)
-    return _ref.paged_decode_ref(q, k_pool, v_pool, pt, pos, window=window)
+    if not use_pallas:
+        return _ref.paged_decode_ref(q, k_pool[layer], v_pool[layer], pt, pos,
+                                     window=window)
+    from repro.kernels.swa_decode import paged_decode
+
+    def kernel(q_, k_, v_, pt_, pos_, layer_):
+        return paged_decode(q_, k_, v_, pt_, pos_, layer=layer_, window=window,
+                            interpret=_interpret(interpret))
+
+    args = (q, k_pool, v_pool, pt, pos, jnp.asarray(layer, jnp.int32))
+    mesh = get_abstract_mesh_or_none()
+    if mesh is None or AxisType.Auto not in mesh.axis_types:
+        return kernel(*args)
+    qs = logical_to_spec(("batch", "kv_heads", None, None), q.shape, mesh)
+    pool = P(None, None, None, qs[1])
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=(qs, pool, pool, P(qs[0], None), P(qs[0]),
+                                   P()),
+                         out_specs=qs, check_vma=False)(*args)

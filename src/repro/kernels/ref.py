@@ -50,7 +50,7 @@ def ssd_ref_heads(x, dt, A, Bh, Ch, chunk):
 def paged_decode_ref(q, k_pool, v_pool, pt, pos, window=None):
     """Paged-cache decode attention oracle: gather every slot's pages into
     a dense (B, PP*ps, N, D) view and run the masked softmax.  q:
-    (B, N, G, D); pools (P, ps, N, D); pt (B, PP) int32; pos (B,) int32.
+    (B, N, G, D); pools (P, ps, N*D); pt (B, PP) int32; pos (B,) int32.
     Positions past ``pos`` (including trash-page placeholders) are masked."""
     b, n, g, d = q.shape
     ps = k_pool.shape[1]

@@ -26,12 +26,12 @@ def _kernel(a_ref, b_ref, h_ref, carry_ref, *, chunk):
     def _init():
         carry_ref[...] = jnp.zeros(carry_ref.shape, carry_ref.dtype)
 
-    a = a_ref[...]  # (bb, chunk, bd)
-    b = b_ref[...]
-
     def step(t, carry):
-        h = a[:, t, :] * carry + b[:, t, :]
-        h_ref[:, t, :] = h
+        # Ref slices (not slices of a loaded value): Mosaic lowers a
+        # dynamic pl.ds row of a VMEM ref, not a dynamic_slice of a vector.
+        row = pl.ds(t, 1)
+        h = a_ref[:, row, :] * carry + b_ref[:, row, :]
+        h_ref[:, row, :] = h
         return h
 
     carry_ref[...] = jax.lax.fori_loop(0, chunk, step, carry_ref[...])
@@ -57,6 +57,6 @@ def rglru_pallas(a, b, *, chunk=128, block_b=8, block_d=128, interpret=False):
         in_specs=[spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bsz, s, d), a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_b, block_d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_b, 1, block_d), jnp.float32)],
         interpret=interpret,
     )(a, b)

@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, carry_ref,
             *, chunk, nchunks):
+    h_idx = pl.program_id(1)
     c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
@@ -33,33 +34,45 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, carry_ref,
 
     x = x_ref[0].astype(jnp.float32)        # (q, p)
     dt = dt_ref[0].astype(jnp.float32)      # (q, 1)... stored (q, 1)
-    A = a_ref[0, 0]                         # scalar decay rate for this head
+    A = a_ref[h_idx]                        # scalar decay rate (SMEM)
     B = b_ref[0].astype(jnp.float32)        # (q, n)
     C = c_ref[0].astype(jnp.float32)        # (q, n)
 
     q = x.shape[0]
-    dA = dt[:, 0] * A                       # (q,)
-    csum = jnp.cumsum(dA)                   # (q,)
     xb = x * dt                             # (q, p)
 
-    # Intra-chunk decay matrix L[i, j] = exp(csum_i - csum_j) for j <= i.
-    diff = csum[:, None] - csum[None, :]
+    # In-chunk cumulative decay as a lower-triangular matmul (Mosaic has no
+    # cumsum): cs[i, j] = sum_{k <= i} dA_k for every column j, so cs holds
+    # csum_i down its rows and cs.T holds csum_j along its columns.
     tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.where(tri, jnp.exp(diff), 0.0)
+    dA = jnp.broadcast_to(dt * A, (q, q))                               # dA_i
+    cs = jnp.dot(tri.astype(jnp.float32), dA,
+                 precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
+    csum = cs[:, 0:1]                                                   # (q, 1)
+
+    # Intra-chunk decay matrix L[i, j] = exp(csum_i - csum_j) for j <= i.
+    L = jnp.where(tri, jnp.exp(cs - cs.T), 0.0)
 
     scores = jnp.dot(C, B.T, preferred_element_type=jnp.float32) * L   # (q, q)
     y = jnp.dot(scores, xb, preferred_element_type=jnp.float32)        # (q, p)
 
     # Carried-state contribution.
     h_in = carry_ref[...]                                              # (p, n)
-    decay_from_start = jnp.exp(csum)[:, None]                          # (q, 1)
+    decay_from_start = jnp.exp(csum)                                   # (q, 1)
     y = y + decay_from_start * jnp.dot(C, h_in.T, preferred_element_type=jnp.float32)
 
     # State update.
-    total = csum[q - 1]
-    decay_to_end = jnp.exp(total - csum)[:, None]                      # (q, 1)
-    h_new = h_in * jnp.exp(total) + jnp.dot(
+    total = cs[q - 1:q, 0:1]                                           # (1, 1)
+    decay_to_end = jnp.exp(total - csum)                               # (q, 1)
+    # The chunk's total decay as a (1, n) row (a sublane sum), broadcast
+    # down the state's rows: Mosaic cannot broadcast a (1, 1) both ways.
+    n = h_in.shape[1]
+    total_row = jnp.sum(jnp.broadcast_to(dt * A, (q, n)), axis=0,
+                        keepdims=True)
+    chunk_decay = jnp.broadcast_to(jnp.exp(total_row), h_in.shape)
+    h_new = h_in * chunk_decay + jnp.dot(
         (xb * decay_to_end).T, B, preferred_element_type=jnp.float32)  # (p, n)
     carry_ref[...] = h_new
 
@@ -86,7 +99,6 @@ def ssd_pallas(x, dt, A, B, C, chunk, *, interpret=False):
     Bt = B.transpose(0, 2, 1, 3).reshape(b * h, s, n)
     Ct = C.transpose(0, 2, 1, 3).reshape(b * h, s, n)
     dtt = dt.transpose(0, 2, 1).reshape(b * h, s, 1)
-    Ar = jnp.broadcast_to(A[None, :], (b, h)).reshape(b * h, 1)
 
     def idx(i, j, k):
         return (i * h + j, k, 0)
@@ -97,7 +109,7 @@ def ssd_pallas(x, dt, A, B, C, chunk, *, interpret=False):
         in_specs=[
             pl.BlockSpec((1, chunk, p), idx),
             pl.BlockSpec((1, chunk, 1), idx),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i * h + j, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # whole (h,) A vector
             pl.BlockSpec((1, chunk, n), idx),
             pl.BlockSpec((1, chunk, n), idx),
         ],
@@ -111,7 +123,7 @@ def ssd_pallas(x, dt, A, B, C, chunk, *, interpret=False):
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xt, dtt, Ar, Bt, Ct)
+    )(xt, dtt, A.astype(jnp.float32), Bt, Ct)
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     state = state.reshape(b, h, p, n)
     return y, state

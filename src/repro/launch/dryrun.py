@@ -1,5 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# A CPU compile tool: pin the host platform (never take an attached chip)
+# and add 512 host devices to whatever XLA_FLAGS already holds.
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512")
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)
 combination against ShapeDtypeStruct stand-ins and extract the roofline
@@ -9,8 +13,9 @@ terms from the compiled artifact.
         --shape train_4k [--multi-pod] [--buffer-mode clone] [--out out.json]
     PYTHONPATH=src python -m repro.launch.dryrun --all   # every combo
 
-The XLA_FLAGS line above MUST run before any other import (jax locks the
-device count on first init); do not set it anywhere else in the repo.
+The environment lines above MUST run before any other import (jax locks
+the platform and device count on first init); do not set them anywhere
+else in the repo.
 """
 
 import argparse
@@ -28,7 +33,7 @@ from repro.configs import registry
 from repro.launch import specs as S
 from repro.launch import steps as St
 from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh, mesh_context)
+                               make_production_mesh)
 from repro.optim import adamw
 from repro.sharding.rules import named_sharding
 
@@ -154,7 +159,7 @@ def build_combo(arch, shape_name, mesh, buffer_mode="clone", topk=None,
 def _compile_and_measure(arch, shape_name, mesh, buffer_mode, topk, overrides):
     t0 = time.time()
     fn, args = build_combo(arch, shape_name, mesh, buffer_mode, topk, overrides)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

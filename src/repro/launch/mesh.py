@@ -1,9 +1,11 @@
 """Production meshes.
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
-never touches jax device state.  Single pod: 16x16 = 256 chips (TPU v5e
-numbers); multi-pod: 2 pods x 256 = 512 chips with a leading "pod" axis that
-carries pure data parallelism across the inter-pod links.
+never touches jax device state.  ``make_device_mesh`` spans whatever devices
+are attached (one chip, a 2x2 host, a pod); the dry-run's fixed shapes are
+a single pod, 16x16 = 256 chips (TPU v5e numbers), and multi-pod, 2 pods x
+256 = 512 chips with a leading "pod" axis that carries pure data
+parallelism across the inter-pod links.
 """
 
 from __future__ import annotations
@@ -11,48 +13,33 @@ from __future__ import annotations
 import jax
 
 
-def mesh_context(mesh):
-    """Activate ``mesh`` for the enclosing block.
-
-    ``jax.set_mesh`` where available (abstract-mesh context, newer jax),
-    ``jax.sharding.use_mesh`` on intermediate versions, and the legacy
-    ``with mesh:`` resource context otherwise — ``repro.sharding.rules``
-    resolves the active mesh under all three.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # Mesh itself is the legacy context manager
-
-
-def make_abstract_mesh(shape, axes):
-    """Version-compat ``AbstractMesh`` constructor.
-
-    jax moved from ``AbstractMesh(((name, size), ...))`` (<= 0.4.x) to
-    ``AbstractMesh(axis_sizes, axis_names)``; accept the modern
-    ``(shape, axes)`` form and translate for whichever this jax wants.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
-def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax only knows Auto
-    # semantics, which is exactly what we want, so omit the kwarg there.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """The fixed pod shapes the dry-run compiles against (host devices)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return _make_mesh(shape, axes)
+
+
+def device_mesh_shape(n: int):
+    """(data, model) for ``n`` devices: the model axis is the largest power
+    of two not above sqrt(n) — 1 -> (1, 1), 4 -> (2, 2), 8 -> (4, 2),
+    256 -> (16, 16), the production pod."""
+    model = 1
+    while (2 * model) ** 2 <= n and n % (2 * model) == 0:
+        model *= 2
+    return n // model, model
+
+
+def make_device_mesh(devices=None):
+    """A (data, model) mesh over the attached devices (``--full`` runs)."""
+    devices = jax.devices() if devices is None else list(devices)
+    return _make_mesh(device_mesh_shape(len(devices)), ("data", "model"),
+                      devices=devices)
 
 
 def make_test_mesh(devices: int = 8):

@@ -21,7 +21,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry
-from repro.launch.mesh import make_production_mesh, make_test_mesh, mesh_context
+from repro.launch.mesh import make_device_mesh, make_test_mesh
 from repro.models.transformer import Transformer
 from repro.serve import STREAMS, Request, ServeEngine, build_stream
 from repro.serve import legacy as legacy_mod
@@ -59,6 +59,8 @@ def summarize(finished, wall_seconds):
 
 
 def main(argv=None):
+    """Serve one stream; returns ``(finished requests, engine)`` (the
+    engine is None under ``--legacy``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=registry.list_archs())
     ap.add_argument("--full", action="store_true")
@@ -91,9 +93,9 @@ def main(argv=None):
         raise SystemExit("encoder-only arch has no decode step")
     if args.ring:
         cfg = dataclasses.replace(cfg, sliding_window=32, ring_cache=True)
-    mesh = make_production_mesh() if args.full else make_test_mesh()
+    mesh = make_device_mesh() if args.full else make_test_mesh()
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(args.seed))
     if args.ring and args.paged:
         raise SystemExit("--ring and --paged are exclusive (the page pool "
@@ -106,11 +108,12 @@ def main(argv=None):
                         shared_prefix=args.shared_prefix)
 
     t0 = time.perf_counter()
+    engine = None
     if args.legacy:
         finished = legacy_mod.simulate(cfg, params, reqs, args.slots,
                                        args.max_len, mesh)
     else:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             # built inside the mesh scope so the jitted state init shares
             # the step outputs' shardings (one compile per executable)
             engine = ServeEngine(cfg, params, slots=args.slots,
@@ -135,8 +138,10 @@ def main(argv=None):
               f"({engine.resident_cache_bytes()} B resident vs "
               f"{engine.slots * engine.pages_per_slot * engine.cache_page_bytes()}"
               f" B dense-equivalent)")
-    return finished
+    return finished, engine
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

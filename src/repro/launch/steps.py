@@ -71,14 +71,13 @@ def _chunked_bkd_loss(cfg: LMConfig, student, teacher, buffer_params, batch,
                           "using the jnp chunked loss")
         if loss_backend == "pallas" and m is None and not topk:
             from repro.kernels import ops
-            interpret = jax.default_backend() != "tpu"
             lb2 = None
             if h_b is not None:
                 lb2 = jax.lax.stop_gradient(from_hidden(buffer_params, sl(h_b)))
                 lb2 = distill._mask_pad(lb2.reshape(-1, lb2.shape[-1]), vocab)
             flat = lambda a: distill._mask_pad(a.reshape(-1, a.shape[-1]), vocab)
             loss = ops.kd_loss(y.reshape(-1), flat(ls), flat(lt), lb2, tau,
-                               use_pallas=True, interpret=interpret)
+                               use_pallas=True)
             if cached_buffer_logits is not None:
                 c = cached_buffer_logits
                 loss = loss + distill.topk_kl_cached(

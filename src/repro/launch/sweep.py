@@ -12,7 +12,9 @@ compiles are independent), writing one JSON per combo plus a failures log.
 sampling, partial participation, random delays, and the event-driven
 `async_*` simulator scenarios with emergent staleness — see
 docs/scenarios.md) runs through the `repro.launch.train` driver, one
-subprocess per scenario, writing one log per scenario.
+subprocess per scenario, writing one log per scenario.  The children run
+one at a time (`-j` is ignored) unless `JAX_PLATFORMS=cpu` pins them to the
+host: a chip belongs to one process at a time.
 """
 
 from __future__ import annotations
@@ -139,10 +141,15 @@ def main():
             ap.error(str(e))
     os.makedirs(args.out, exist_ok=True)
     results = []
-    with ThreadPoolExecutor(args.j) as ex:
+    workers = args.j
+    if args.scenarios and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # Each train child takes the accelerator; a chip holds one process
+        # at a time, so unpinned children run one after another.
+        workers = 1
+    with ThreadPoolExecutor(workers) as ex:
         if args.scenarios:
             names = scenario_list()
-            print(f"{len(names)} scenarios -> {args.out} ({args.j} workers)")
+            print(f"{len(names)} scenarios -> {args.out} ({workers} workers)")
             futs = [ex.submit(run_scenario, n, args.out, args.rounds,
                               args.steps_per_phase, args.method,
                               args.loss_backend, args.transport)

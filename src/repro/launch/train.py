@@ -32,7 +32,7 @@ from repro.core.simulator import (DistillOnArrival, EventDrivenSimulator,
 from repro.data import make_token_stream
 from repro.launch import specs as S
 from repro.launch import steps as St
-from repro.launch.mesh import make_production_mesh, make_test_mesh, mesh_context
+from repro.launch.mesh import make_device_mesh, make_test_mesh
 from repro.models.transformer import Transformer
 from repro.optim import adamw
 from repro.transport import codec_names, parse_codec
@@ -52,7 +52,7 @@ def eval_nll(cfg, params, tokens, batch, seq, mesh, n_batches=4, seed=1):
     from repro.core import distill
     apply_fn = jax.jit(Transformer.apply, static_argnums=0)
     tot = jnp.zeros(())
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         for b in lm_batches(tokens, batch, seq, n_batches, seed):
             logits, _ = apply_fn(cfg, params, {"tokens": b["tokens"]})
             tot = tot + distill.ce_loss(logits, b["labels"],
@@ -138,7 +138,7 @@ def main(argv=None):
     if cfg.is_encoder or cfg.is_vlm:
         raise SystemExit("train.py drives token-LM FL; see examples/ for "
                          "encoder/VLM paths")
-    mesh = make_production_mesh() if args.full else make_test_mesh(len(jax.devices()))
+    mesh = make_device_mesh() if args.full else make_test_mesh(len(jax.devices()))
     print(f"arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"method={args.method}")
 
@@ -220,7 +220,7 @@ def main(argv=None):
         source = build_scenario(args.scenario, num_edges=args.edges,
                                 seed=args.seed)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(args.seed))
         opt_state = opt.init(params)
         jit_pre = jax.jit(pre_step, donate_argnums=(0, 1))
@@ -329,4 +329,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -324,11 +324,11 @@ def _assert_pageable(cfg: LMConfig):
 
 
 def _paged_subblock_decode(cfg: LMConfig, kind: str, p, pool, pt, x, pos,
-                           positions, active, page_size):
+                           positions, active, page_size, layer=None):
     h = _norm_apply(cfg, p["ln1"], x)
     y, pool = attn_lib.paged_decode_step(
         p["mixer"], cfg.attn_cfg(kind == "local"), pool, pt, h, pos,
-        positions, active, page_size=page_size)
+        positions, active, page_size=page_size, layer=layer)
     x = x + y
     if cfg.mlp != "none":
         m, _ = _mlp_apply(cfg, p["mlp"], _norm_apply(cfg, p["ln2"], x))
@@ -541,7 +541,7 @@ class Transformer:
     @staticmethod
     def init_paged_cache(cfg: LMConfig, num_pages, page_size):
         """Per-layer physical page pools, mirroring :meth:`init_cache`'s
-        tree shape ((layers, P, ps, N, D) under "blocks", (P, ps, N, D) for
+        tree shape ((layers, P, ps, N*D) under "blocks", (P, ps, N*D) for
         tails).  One page table indexes every layer's pool — the logical ->
         physical mapping is per slot, not per layer."""
         _assert_pageable(cfg)
@@ -579,7 +579,9 @@ class Transformer:
                           active=None, *, page_size):
         """One token per slot against the paged pools.  token: (B, 1) int32;
         pos: (B,) int32; pt: (B, PP) int32; ``active`` (B,) bool redirects
-        inactive rows' cache writes to the trash page."""
+        inactive rows' cache writes to the trash page.  The layer scan
+        carries the stacked pools and each layer writes and reads its own
+        in place, so a tick touches the pages it attends, not whole pools."""
         batch = {"tokens": token}
         x, _ = Transformer._embed_inputs(cfg, params, batch)
         pos = jnp.asarray(pos, jnp.int32)
@@ -587,19 +589,21 @@ class Transformer:
         if cfg.rope == "mrope":
             positions = jnp.broadcast_to(positions[:, None, :], (x.shape[0], 3, 1))
 
-        def super_step(x, scanned):
-            layer_p, pool = scanned
-            new_pool = {}
+        def super_step(carry, scanned):
+            x, pools = carry
+            layer_p, layer = scanned
+            pools = dict(pools)
             for i, kind in enumerate(cfg.block_pattern):
-                x, new_pool[f"b{i}"] = _paged_subblock_decode(
-                    cfg, kind, layer_p[f"b{i}"], pool[f"b{i}"], pt, x, pos,
-                    positions, active, page_size)
-            return x, new_pool
+                x, pools[f"b{i}"] = _paged_subblock_decode(
+                    cfg, kind, layer_p[f"b{i}"], pools[f"b{i}"], pt, x, pos,
+                    positions, active, page_size, layer)
+            return (x, pools), None
 
         new_caches = {}
         if cfg.n_super > 0:
-            x, new_caches["blocks"] = _scan_layers(
-                cfg, super_step, x, (params["blocks"], caches["blocks"]))
+            (x, new_caches["blocks"]), _ = _scan_layers(
+                cfg, super_step, (x, caches["blocks"]),
+                (params["blocks"], jnp.arange(cfg.n_super, dtype=jnp.int32)))
         for j in range(cfg.n_tail):
             kind = cfg.block_pattern[j % len(cfg.block_pattern)]
             x, new_caches[f"tail{j}"] = _paged_subblock_decode(
@@ -615,35 +619,38 @@ class Transformer:
         sharing a static ``n_prefix_pages``).  ``batch["tokens"]`` holds
         the right-padded suffixes and ``batch["positions"]`` their absolute
         positions (``n_prefix_pages * page_size`` onward); returns suffix
-        logits plus the updated pools."""
+        logits plus the updated pools.  Like :meth:`paged_decode_step` the
+        layer scan carries the stacked pools and updates them in place."""
         x, positions = Transformer._embed_inputs(cfg, params, batch)
 
-        def block_prefill(p, kind, x, pool):
+        def block_prefill(p, kind, x, pool, layer=None):
             h = _norm_apply(cfg, p["ln1"], x)
             y, pool = attn_lib.paged_prefill(
                 p["mixer"], cfg.attn_cfg(kind == "local"), h, positions,
-                pool, pt, lengths, fill, n_prefix_pages, page_size)
+                pool, pt, lengths, fill, n_prefix_pages, page_size, layer)
             x = x + y
             if cfg.mlp != "none":
                 m, _ = _mlp_apply(cfg, p["mlp"], _norm_apply(cfg, p["ln2"], x))
                 x = x + m
             return x, pool
 
-        def super_fwd(x, scanned):
-            layer_p, pool = scanned
-            new_pool = {}
+        def super_fwd(carry, scanned):
+            x, pools = carry
+            layer_p, layer = scanned
+            pools = dict(pools)
             for i, kind in enumerate(cfg.block_pattern):
-                x, new_pool[f"b{i}"] = block_prefill(
-                    layer_p[f"b{i}"], kind, x, pool[f"b{i}"])
-            return x, new_pool
+                x, pools[f"b{i}"] = block_prefill(
+                    layer_p[f"b{i}"], kind, x, pools[f"b{i}"], layer)
+            return (x, pools), None
 
         new_caches = {}
         if cfg.n_super > 0:
             body = super_fwd
             if cfg.remat == "block":
                 body = jax.checkpoint(body)
-            x, new_caches["blocks"] = _scan_layers(
-                cfg, body, x, (params["blocks"], caches["blocks"]))
+            (x, new_caches["blocks"]), _ = _scan_layers(
+                cfg, body, (x, caches["blocks"]),
+                (params["blocks"], jnp.arange(cfg.n_super, dtype=jnp.int32)))
         for j in range(cfg.n_tail):
             kind = cfg.block_pattern[j % len(cfg.block_pattern)]
             x, new_caches[f"tail{j}"] = block_prefill(

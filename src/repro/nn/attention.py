@@ -229,18 +229,33 @@ def prefill(params, cfg: AttnConfig, x, positions, max_len, lengths=None):
 def init_paged_cache(cfg: AttnConfig, num_pages, page_size, dtype=jnp.bfloat16):
     """One block-paged KV pool: physical page p's K/V for positions
     ``[t*page_size, (t+1)*page_size)`` of whichever slot's page table maps
-    logical page t to p.  Page 0 is the trash page (never attended)."""
-    shape = (num_pages, page_size, cfg.kv_heads, cfg.head_dim)
+    logical page t to p.  Page 0 is the trash page (never attended).
+
+    A page is (page_size, N*D): the kv heads sit side by side in the last
+    dim.  With head_dim under the TPU's 128 lanes a separate (N, D) pair
+    would be padded, so XLA would pick another layout for the pool and
+    transpose it around the decode kernel on every call."""
+    shape = (num_pages, page_size, cfg.kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def paged_cache_specs():
-    return {"k": (None, None, "kv_heads", None),
-            "v": (None, None, "kv_heads", None)}
+    return {"k": (None, None, "kv_heads"), "v": (None, None, "kv_heads")}
+
+
+def _write_pages(pool, layer, page, off, k, v):
+    """Scatter K/V (page.shape + (N, D)) into the pools at (page, off) —
+    of ``layer`` when the pools are stacked over layers."""
+    at, axes = (page, off), (None, None, "kv_heads")
+    if layer is not None:
+        at, axes = (layer, *at), ("layers", *axes)
+    put = lambda p, t: constrain(
+        p.at[at].set(t.reshape(*page.shape, -1).astype(p.dtype)), axes)
+    return put(pool["k"], k), put(pool["v"], v)
 
 
 def paged_prefill(params, cfg: AttnConfig, x, positions, pool, pt, lengths,
-                  fill, n_prefix_pages, page_size):
+                  fill, n_prefix_pages, page_size, layer=None):
     """Prompt-suffix forward against a block-paged pool.
 
     ``x`` (B, L, D) embeds the right-padded prompt *suffixes* of one
@@ -253,7 +268,8 @@ def paged_prefill(params, cfg: AttnConfig, x, positions, pool, pt, lengths,
     attention runs over [gathered prefix, computed suffix] with the causal
     / window mask at absolute positions.  Scatter-before-gather means an
     admission *later in the same tick* (a higher ``n_prefix_pages`` group)
-    sees pages this group just wrote.
+    sees pages this group just wrote.  ``pool`` and ``layer`` are as in
+    :func:`paged_decode_step`.
 
     With ``n_prefix_pages == 0`` the attention is literally
     ``attend_full(q, k, v)`` — bit-identical math to the dense
@@ -265,11 +281,11 @@ def paged_prefill(params, cfg: AttnConfig, x, positions, pool, pt, lengths,
     valid = fill[:, None] & (jnp.arange(s_len)[None, :] < lengths[:, None])
     page = jnp.where(valid, jnp.take(pt, apos // page_size, axis=1), 0)
     off = jnp.broadcast_to((apos % page_size)[None, :], (b, s_len))
-    pk = pool["k"].at[page, off].set(k.astype(pool["k"].dtype))
-    pv = pool["v"].at[page, off].set(v.astype(pool["v"].dtype))
+    pk, pv = _write_pages(pool, layer, page, off, k, v)
     if n_prefix_pages:
         def gather(p):
-            g = p[pt[:, :n_prefix_pages]]                     # (B, npp, ps, N, D)
+            pages = pt[:, :n_prefix_pages]
+            g = p[pages] if layer is None else p[layer, pages]  # (B, npp, ps, N*D)
             return g.reshape(b, start, cfg.kv_heads, cfg.head_dim).astype(q.dtype)
         kc = jnp.concatenate([gather(pk), k], axis=1)
         vc = jnp.concatenate([gather(pv), v], axis=1)
@@ -280,22 +296,23 @@ def paged_prefill(params, cfg: AttnConfig, x, positions, pool, pt, lengths,
     else:
         out = attend_full(q, k, v, cfg)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
-    pk = constrain(pk, (None, None, "kv_heads", None))
-    pv = constrain(pv, (None, None, "kv_heads", None))
     return constrain(y, ("batch", None, "embed_act")), {"k": pk, "v": pv}
 
 
 def paged_decode_step(params, cfg: AttnConfig, pool, pt, x, pos, positions=None,
-                      active=None, *, page_size, use_pallas=None,
-                      interpret=False):
+                      active=None, *, page_size, layer=None, use_pallas=None,
+                      interpret=None):
     """One token against the block-paged pool.  x: (B, 1, D); pos: (B,)
-    int32 per-slot positions; pt: (B, PP) int32 page table.  Rows with
-    ``active`` False write their K/V to the trash page — a freed slot's
-    stale table may point at pages since reallocated to another slot, so
-    unlike the dense cache its junk writes must be *redirected*, not merely
-    overwritten later.  The attention gather runs through
-    :func:`repro.kernels.ops.paged_decode_attn` (Pallas on TPU, dense-view
-    reference elsewhere)."""
+    int32 per-slot positions; pt: (B, PP) int32 page table.  ``pool`` is
+    one layer's (P, ps, N*D) pools, or with ``layer`` the (L, P, ps, N*D)
+    pools of every layer: the token's K/V is written at ``layer`` and the
+    kernel reads that layer in place, so a layer scan that carries the
+    stacked pools copies no pool.  Rows with ``active`` False write their
+    K/V to the trash page — a freed slot's stale table may point at pages
+    since reallocated to another slot, so unlike the dense cache its junk
+    writes must be *redirected*, not merely overwritten later.  The
+    attention gather runs through :func:`repro.kernels.ops.paged_decode_attn`
+    (Pallas on TPU, dense-view reference elsewhere)."""
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     if positions is None:
@@ -308,14 +325,12 @@ def paged_decode_step(params, cfg: AttnConfig, pool, pt, x, pos, positions=None,
     if active is not None:
         page = jnp.where(active, page, 0)
     off = pos % page_size
-    pk = pool["k"].at[page, off].set(k[:, 0].astype(pool["k"].dtype))
-    pv = pool["v"].at[page, off].set(v[:, 0].astype(pool["v"].dtype))
-    pk = constrain(pk, (None, None, "kv_heads", None))
-    pv = constrain(pv, (None, None, "kv_heads", None))
+    pk, pv = _write_pages(pool, layer, page, off, k[:, 0], v[:, 0])
     from repro.kernels import ops  # local import: kernels must not be a hard dep of nn
     qg = q[:, 0].reshape(b, cfg.kv_heads, cfg.q_groups, cfg.head_dim)
-    out = ops.paged_decode_attn(qg, pk, pv, pt, pos, window=cfg.window,
-                                use_pallas=use_pallas, interpret=interpret)
+    out = ops.paged_decode_attn(qg, pk, pv, pt, pos, layer=layer,
+                                window=cfg.window, use_pallas=use_pallas,
+                                interpret=interpret)
     out = out.reshape(b, 1, cfg.num_heads, cfg.head_dim).astype(x.dtype)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return constrain(y, ("batch", None, "embed_act")), {"k": pk, "v": pv}

@@ -248,6 +248,17 @@ def make_paged_decode_tick(cfg: LMConfig, max_len: int, sampler,
     return tick
 
 
+def make_paged_steps(cfg: LMConfig, max_len: int, sampler, page_size: int):
+    """The paged engine's jitted (admission, decode tick).  The pools are
+    donated: both write a few pages and hand the pools back, so XLA updates
+    them in place instead of copying every layer's pool per call."""
+    admit = jax.jit(make_paged_admit_step(cfg, max_len, sampler, page_size),
+                    static_argnames=("npp",), donate_argnums=(1,))
+    tick = jax.jit(make_paged_decode_tick(cfg, max_len, sampler, page_size),
+                   donate_argnums=(1,))
+    return admit, tick
+
+
 def make_paged_init_state(cfg: LMConfig, slots: int, num_pages: int,
                           page_size: int, pages_per_slot: int):
     """Paged twin of :func:`make_init_state`: pools + page table instead of
@@ -310,11 +321,8 @@ class ServeEngine:
             # engine would admit (the scheduling half of the parity suite).
             self.num_pages = num_pages or slots * self.pages_per_slot + 1
             self._alloc = PageAllocator(self.num_pages, page_size)
-            self._admit_fn = jax.jit(
-                make_paged_admit_step(cfg, max_len, sampler, page_size),
-                static_argnames=("npp",))
-            self._tick_fn = jax.jit(
-                make_paged_decode_tick(cfg, max_len, sampler, page_size))
+            self._admit_fn, self._tick_fn = make_paged_steps(
+                cfg, max_len, sampler, page_size)
             self._init_fn = jax.jit(make_paged_init_state(
                 cfg, slots, self.num_pages, page_size, self.pages_per_slot))
         else:
@@ -749,11 +757,10 @@ def simulate(cfg, params, requests, slots, max_len, mesh=None, log=print,
              **engine_kw):
     """Drop-in functional wrapper matching the legacy ``simulate``
     signature: build an engine, serve the request list, return finished."""
-    from repro.launch.mesh import mesh_context
     if mesh is None:
         return ServeEngine(cfg, params, slots=slots, max_len=max_len,
                            **engine_kw).run(requests, log=log)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         # built inside the mesh scope: the jitted state init only matches
         # the step outputs' shardings under the same active mesh
         engine = ServeEngine(cfg, params, slots=slots, max_len=max_len,

@@ -31,7 +31,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import mesh_context
 from repro.models.transformer import Transformer
 
 
@@ -80,7 +79,7 @@ def simulate(cfg, params, requests, slots, max_len, mesh, log=print):
         req.out.append(nxt)
         return len(req.prompt)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         while queue or any(a is not None for a in active):
             # admit arrivals into free slots
             for s in range(slots):

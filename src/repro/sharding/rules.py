@@ -17,7 +17,7 @@ from typing import Mapping, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,34 +123,18 @@ def tree_shardings(specs_tree, shapes_tree, mesh, rules: LogicalRules = DEFAULT_
 
 
 def constrain(x, logical_axes: Sequence[str | None], rules: LogicalRules = DEFAULT_RULES):
-    """with_sharding_constraint by logical names; no-op outside a mesh context.
-
-    Works under both ``jax.set_mesh(mesh)`` (abstract-mesh context — the
-    constraint is expressed as a bare PartitionSpec) and the legacy
-    ``with mesh:`` resource context."""
+    """with_sharding_constraint by logical names (the constraint is a bare
+    PartitionSpec).  A no-op outside a ``jax.set_mesh`` context, and inside
+    a ``shard_map`` that made every axis Manual: per-shard code has nothing
+    left to constrain."""
     mesh = get_abstract_mesh_or_none()
-    if mesh is None:
+    if mesh is None or AxisType.Auto not in mesh.axis_types:
         return x
     spec = logical_to_spec(logical_axes, x.shape, mesh, rules)
     return jax.lax.with_sharding_constraint(x, spec)
 
 
 def get_abstract_mesh_or_none():
-    """Return the mesh from jax.set_mesh / `with mesh:` context, if any."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass  # older jax: no get_abstract_mesh / no .empty
-    try:
-        import warnings
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            env = jax.interpreters.pxla.thread_resources.env
-        mesh = env.physical_mesh
-        if mesh.empty:
-            return None
-        return mesh
-    except AttributeError:
-        return None  # thread_resources layout changed across jax versions
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh

@@ -43,7 +43,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.buffer import TAIL_MASS_FLOOR, reconstruct_logits
+from repro.core.buffer import reconstruct_logits
+from repro.core.distill import tail_logsumexp
 
 #: head name -> Codec subclass.  Populated by :func:`register_codec`.
 CODECS: dict = {}
@@ -215,12 +216,8 @@ class TopK(Codec):
     def encode(self, logits):
         k = self._k(logits.shape[-1])
         tv, ti = jax.lax.top_k(logits, k)
-        full_lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        top_lse = jax.scipy.special.logsumexp(tv, axis=-1)
-        diff = jnp.exp(jnp.minimum(top_lse - full_lse, 0.0))
-        tail = full_lse + jnp.log(jnp.maximum(1.0 - diff, TAIL_MASS_FLOOR))
         return {"top_vals": tv, "top_idx": ti.astype(jnp.int32),
-                "tail_lse": tail}
+                "tail_lse": tail_logsumexp(logits, ti)}
 
     def decode(self, payload, vocab=None):
         tv, ti = payload["top_vals"], payload["top_idx"]

@@ -143,8 +143,7 @@ class TransportMethod(DistillMethod):
                     p1["codes"], lg.shape[-1])
                 return ops.kd_loss_quant(
                     y, lg, codes, p1["scale"], p1["zero"], bl,
-                    ctx.cfg.tau, use_pallas=True,
-                    interpret=jax.default_backend() != "tpu")
+                    ctx.cfg.tau, use_pallas=True)
             dec = self.codec.decode_stacked(payload, vocab=lg.shape[-1])
             return self.inner.loss(ctx, lg, dec, y, x=x,
                                    student_state=student_state,

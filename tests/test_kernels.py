@@ -181,8 +181,8 @@ def test_paged_decode_kernel(window, ps, pp):
     b, n, g, d = 3, 2, 4, 32
     num_pages = b * pp + 1
     q = jax.random.normal(ks[0], (b, n, g, d))
-    kp = jax.random.normal(ks[1], (num_pages, ps, n, d))
-    vp = jax.random.normal(ks[2], (num_pages, ps, n, d))
+    kp = jax.random.normal(ks[1], (num_pages, ps, n * d))
+    vp = jax.random.normal(ks[2], (num_pages, ps, n * d))
     pt = jax.random.randint(ks[3], (b, pp), 0, num_pages).astype(jnp.int32)
     pos = jnp.asarray([0, ps * pp // 2, ps * pp - 1], jnp.int32)
     got = ops.paged_decode_attn(q, kp, vp, pt, pos, window=window,
@@ -202,10 +202,10 @@ def test_paged_decode_identity_table_matches_dense():
     kc = jax.random.normal(ks[1], (b, w, n, d))
     vc = jax.random.normal(ks[2], (b, w, n, d))
     # slot b's logical page t -> physical page b*pp + t (+1 for trash at 0)
-    kp = jnp.concatenate([jnp.zeros((1, ps, n, d)),
-                          kc.reshape(b * pp, ps, n, d)])
-    vp = jnp.concatenate([jnp.zeros((1, ps, n, d)),
-                          vc.reshape(b * pp, ps, n, d)])
+    kp = jnp.concatenate([jnp.zeros((1, ps, n * d)),
+                          kc.reshape(b * pp, ps, n * d)])
+    vp = jnp.concatenate([jnp.zeros((1, ps, n * d)),
+                          vc.reshape(b * pp, ps, n * d)])
     pt = (1 + jnp.arange(b * pp, dtype=jnp.int32)).reshape(b, pp)
     pos = jnp.asarray([37, 127], jnp.int32)
     got = ops.paged_decode_attn(q, kp, vp, pt, pos, use_pallas=True,
@@ -222,8 +222,8 @@ def test_paged_decode_shared_prefix_pages():
     b, n, g, d, ps, pp = 2, 2, 2, 32, 8, 4
     num_pages = 16
     q = jax.random.normal(ks[0], (b, n, g, d))
-    kp = jax.random.normal(ks[1], (num_pages, ps, n, d))
-    vp = jax.random.normal(ks[2], (num_pages, ps, n, d))
+    kp = jax.random.normal(ks[1], (num_pages, ps, n * d))
+    vp = jax.random.normal(ks[2], (num_pages, ps, n * d))
     # both slots share physical pages 1, 2 for logical pages 0, 1
     pt_shared = jnp.asarray([[1, 2, 3, 4], [1, 2, 5, 6]], jnp.int32)
     pos = jnp.asarray([ps * 3 - 1, ps * 4 - 1], jnp.int32)
@@ -241,8 +241,8 @@ def test_paged_decode_bf16():
     ks = jax.random.split(jax.random.key(1), 4)
     b, n, g, d, ps, pp = 2, 2, 2, 64, 16, 4
     q = jax.random.normal(ks[0], (b, n, g, d)).astype(jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (9, ps, n, d)).astype(jnp.bfloat16)
-    vp = jax.random.normal(ks[2], (9, ps, n, d)).astype(jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (9, ps, n * d)).astype(jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (9, ps, n * d)).astype(jnp.bfloat16)
     pt = jax.random.randint(ks[3], (b, pp), 0, 9).astype(jnp.int32)
     pos = jnp.asarray([20, 63], jnp.int32)
     got = ops.paged_decode_attn(q, kp, vp, pt, pos, use_pallas=True,
@@ -250,6 +250,26 @@ def test_paged_decode_bf16():
     want = ref.paged_decode_ref(q, kp, vp, pt, pos)
     np.testing.assert_allclose(got.astype(jnp.float32),
                                want.astype(jnp.float32), rtol=3e-2, atol=3e-2)
+
+
+def test_paged_decode_reads_the_given_layer_of_stacked_pools():
+    """The engine's layer scan hands the kernel every layer's pools and a
+    traced layer index: the kernel must attend that layer's pages only."""
+    ks = jax.random.split(jax.random.key(3), 4)
+    layers, b, n, g, d, ps, pp = 3, 2, 2, 2, 32, 8, 4
+    num_pages = b * pp + 1
+    q = jax.random.normal(ks[0], (b, n, g, d))
+    kp = jax.random.normal(ks[1], (layers, num_pages, ps, n * d))
+    vp = jax.random.normal(ks[2], (layers, num_pages, ps, n * d))
+    pt = (1 + jax.random.permutation(ks[3], b * pp)).reshape(b, pp)
+    pt = pt.astype(jnp.int32)
+    pos = jnp.asarray([ps * 2 + 3, ps * pp - 1], jnp.int32)
+    attn = jax.jit(lambda layer: ops.paged_decode_attn(
+        q, kp, vp, pt, pos, layer=layer, use_pallas=True, interpret=True))
+    for layer in range(layers):
+        want = ref.paged_decode_ref(q, kp[layer], vp[layer], pt, pos)
+        np.testing.assert_allclose(attn(jnp.int32(layer)), want,
+                                   rtol=3e-5, atol=3e-5)
 
 
 # -- dequant-fused KD loss (transport subsystem) ------------------------------

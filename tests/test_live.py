@@ -33,7 +33,7 @@ import pytest
 from repro.configs import registry
 from repro.core.fl import FederatedKD, FLConfig, mlp_adapter
 from repro.data import Dataset, dirichlet_partition, make_synthetic_classification
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.live import LiveSystem, LiveTrainer, lm_adapter, lm_fl_data
 from repro.models.transformer import Transformer
 from repro.serve import Request, ServeEngine, build_stream
@@ -119,7 +119,7 @@ def _tail_only_setup():
     cfg = registry.get_smoke_config("granite-3-2b")
     cfg = dataclasses.replace(cfg, block_pattern=("attn",) * 3)
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params1, _ = Transformer.init(cfg, jax.random.key(0))
         params2, _ = Transformer.init(cfg, jax.random.key(1))
     return cfg, params1, params2, mesh
@@ -165,7 +165,7 @@ def test_hot_swap_atomic_at_every_tick_offset():
         return [Request(rid=0, arrival=0, prompt=p0, max_new=6),
                 Request(rid=1, arrival=2, prompt=p1, max_new=5)]
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params1, slots=2, max_len=max_len)
         engine.run(mk_reqs(), log=None)
         total_ticks = engine.ticks          # invariant across offsets: the
@@ -209,7 +209,7 @@ def test_paged_hot_swap_atomic_at_every_tick_offset():
         return [Request(rid=0, arrival=0, prompt=p0, max_new=6),
                 Request(rid=1, arrival=2, prompt=p1, max_new=5)]
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params1, slots=2, max_len=max_len,
                              paged=True, page_size=8)
         engine.run(mk_reqs(), log=None)
@@ -261,7 +261,7 @@ def test_paged_engine_carry_restore_resume_bit_identical(tmp_path):
         return ServeEngine(cfg, params1, slots=2, max_len=64,
                            paged=True, page_size=8)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         eng_a = mk_engine()
         done_a = eng_a.run(mk_reqs(), log=None)
 
@@ -290,7 +290,7 @@ def test_paged_engine_carry_restore_resume_bit_identical(tmp_path):
 
 def test_commit_swap_requires_stage():
     cfg, params1, _, mesh = _tail_only_setup()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params1, slots=1, max_len=16)
         with pytest.raises(RuntimeError, match="stage_params"):
             engine.commit_swap()
@@ -309,7 +309,7 @@ def test_engine_reset_bit_reproducible():
         return [Request(rid=0, arrival=0, prompt=p0, max_new=5),
                 Request(rid=1, arrival=1, prompt=p1, max_new=4)]
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params1, slots=2, max_len=32,
                              sample="topk", temperature=0.7, top_k=4, seed=11)
 
@@ -366,7 +366,7 @@ def test_warm_coscheduler_steady_state_zero_compile(trace_guard):
     compile — distill microbatch, decode tick, and hot-swap included."""
     cfg, flcfg, core, edges, test = _lm_setup(rounds=4)
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         system = _mk_system(cfg, flcfg, core, edges, test)
         eng, trainer = system.engine, system.trainer
         eng.begin(_lm_stream(cfg), log=None)
@@ -396,7 +396,7 @@ def test_live_checkpoint_save_restore_resume(tmp_path):
     cfg, flcfg, core, edges, test = _lm_setup(rounds=2)
     mesh = make_test_mesh()
     path = str(tmp_path / "live.npz")
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         sys_a = _mk_system(cfg, flcfg, core, edges, test)
         done_a = sys_a.run(_lm_stream(cfg), log=None)
 

@@ -15,14 +15,14 @@ import numpy as np
 
 from repro.configs import registry
 from repro.launch import serve
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.models.transformer import Transformer
 
 
 def _smoke_setup():
     cfg = registry.get_smoke_config("granite-3-2b")
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(0))
     return cfg, params, mesh
 
@@ -51,7 +51,7 @@ def test_admission_is_single_batched_prefill_forward(monkeypatch):
                           prompt=rng.integers(0, cfg.vocab_size - 1, size=6),
                           max_new=3)
             for i in range(2)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = serve.ServeEngine(cfg, params, slots=2, max_len=24)
         finished = engine.run(reqs, log=None)
     assert len(finished) == 2
@@ -67,7 +67,7 @@ def test_first_token_from_prefill_matches_full_forward():
     cfg, params, mesh = _smoke_setup()
     toks = jax.random.randint(jax.random.key(1), (1, 8), 0,
                               cfg.vocab_size - 1)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lg_pre, _ = Transformer.prefill(cfg, params, {"tokens": toks}, 16)
         lg_full, _ = Transformer.apply(cfg, params, {"tokens": toks})
     np.testing.assert_allclose(lg_pre[0, -1], lg_full[0, -1],
@@ -88,7 +88,7 @@ def test_padded_batched_prefill_rows_match_exact_length():
     for i, p in enumerate(prompts):
         padded[i, :len(p)] = p
     lengths = jnp.asarray([len(p) for p in prompts], jnp.int32)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lg_b, _ = Transformer.prefill(cfg, params,
                                       {"tokens": jnp.asarray(padded)},
                                       max_len, lengths=lengths)

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 
 from repro.configs import registry
-from repro.launch.mesh import make_test_mesh, mesh_context
+from repro.launch.mesh import make_test_mesh
 from repro.models.transformer import Transformer
 from repro.serve import (STREAMS, Request, ServeEngine, build_stream,
                          bucket_length)
@@ -35,7 +35,7 @@ def _setup(ring=False):
     if ring:
         cfg = dataclasses.replace(cfg, sliding_window=8, ring_cache=True)
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(0))
     return cfg, params, mesh
 
@@ -79,7 +79,7 @@ def test_staggered_admission_matches_sequential(ring):
     max_len = 48
     reqs = [Request(rid=0, arrival=0, prompt=_prompt(rng, cfg, 6), max_new=10),
             Request(rid=1, arrival=2, prompt=_prompt(rng, cfg, 11), max_new=8)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = {r.rid: sequential_decode(cfg, params, r.prompt, r.max_new,
                                          max_len) for r in reqs}
         engine = ServeEngine(cfg, params, slots=2, max_len=max_len)
@@ -100,7 +100,7 @@ def test_legacy_loop_has_the_shared_ptick_bug():
     max_len = 48
     reqs = [Request(rid=0, arrival=0, prompt=_prompt(rng, cfg, 6), max_new=10),
             Request(rid=1, arrival=2, prompt=_prompt(rng, cfg, 11), max_new=8)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = {r.rid: sequential_decode(cfg, params, r.prompt, r.max_new,
                                          max_len) for r in reqs}
     finished = legacy_mod.simulate(cfg, params, reqs, 2, max_len, mesh,
@@ -123,13 +123,13 @@ def test_max_new_boundary(max_new):
     cfg, params, mesh = _setup()
     rng = np.random.default_rng(5)
     prompt = _prompt(rng, cfg, 7)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=1, max_len=32)
         finished = engine.run([Request(rid=0, arrival=0, prompt=prompt,
                                        max_new=max_new)], log=None)
     assert len(finished) == 1
     assert len(finished[0].out) == max_new
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = sequential_decode(cfg, params, prompt, max_new, 32)
     assert finished[0].out == want
 
@@ -152,7 +152,7 @@ def test_max_len_truncation_edge():
     cfg, params, mesh = _setup()
     rng = np.random.default_rng(7)
     p12, p15 = _prompt(rng, cfg, 12), _prompt(rng, cfg, 15)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=2, max_len=16)
         finished = engine.run(
             [Request(rid=0, arrival=0, prompt=p12, max_new=50),
@@ -171,7 +171,7 @@ def test_bucket_capped_at_max_len():
     cfg, params, mesh = _setup()
     rng = np.random.default_rng(23)
     prompt = _prompt(rng, cfg, 40)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=2, max_len=48)
         finished = engine.run([Request(rid=0, arrival=0, prompt=prompt,
                                        max_new=4)], log=None)
@@ -182,7 +182,7 @@ def test_bucket_capped_at_max_len():
 def test_prompt_longer_than_max_len_rejected():
     cfg, params, mesh = _setup()
     rng = np.random.default_rng(9)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=1, max_len=16)
         with pytest.raises(ValueError, match="max_len"):
             engine.run([Request(rid=0, arrival=0,
@@ -207,7 +207,7 @@ def _tail_only_setup(ring=False):
     if ring:
         cfg = dataclasses.replace(cfg, sliding_window=8, ring_cache=True)
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(0))
     return cfg, params, mesh
 
@@ -232,7 +232,7 @@ def test_engine_parity_vs_legacy(ring):
 
     legacy_out = legacy_mod.simulate(cfg, params, reqs(), 3, max_len, mesh,
                                      log=lambda *a: None)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=3, max_len=max_len)
         engine_out = engine.run(reqs(), log=None)
     assert len(legacy_out) == len(engine_out) == 6
@@ -255,7 +255,7 @@ def test_legacy_layered_cache_admission_bug():
     assert cfg.n_super > 1
     rng = np.random.default_rng(5)
     prompt = _prompt(rng, cfg, 8)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = sequential_decode(cfg, params, prompt, 6, 32)
     finished = legacy_mod.simulate(
         cfg, params, [Request(rid=0, arrival=0, prompt=prompt, max_new=6)],
@@ -271,12 +271,12 @@ def test_recurrent_arch_exact_length_admission():
     sequential decoding."""
     cfg = registry.get_smoke_config("mamba2-370m")
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(0))
     rng = np.random.default_rng(13)
     reqs = [Request(rid=0, arrival=0, prompt=_prompt(rng, cfg, 6), max_new=4),
             Request(rid=1, arrival=1, prompt=_prompt(rng, cfg, 9), max_new=4)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = {r.rid: sequential_decode(cfg, params, r.prompt, r.max_new, 32)
                 for r in reqs}
         engine = ServeEngine(cfg, params, slots=2, max_len=32)
@@ -314,7 +314,7 @@ def test_prefill_compile_count(trace_guard):
         return [Request(rid=i, arrival=3 * i, prompt=_prompt(rng, cfg, n),
                         max_new=2)
                 for i, n in enumerate(lengths)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=2, max_len=80)
         with trace_guard(engine._admit_fn, max_compiles=bound):
             engine.run(mk_reqs(), log=None)
@@ -383,7 +383,7 @@ def test_paged_staggered_admission_matches_sequential():
     max_len = 48
     reqs = [Request(rid=0, arrival=0, prompt=_prompt(rng, cfg, 6), max_new=10),
             Request(rid=1, arrival=2, prompt=_prompt(rng, cfg, 11), max_new=8)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = {r.rid: sequential_decode(cfg, params, r.prompt, r.max_new,
                                          max_len) for r in reqs}
         engine = ServeEngine(cfg, params, slots=2, max_len=max_len,
@@ -400,7 +400,7 @@ def test_paged_max_new_boundary(max_new):
     cfg, params, mesh = _setup()
     rng = np.random.default_rng(5)
     prompt = _prompt(rng, cfg, 7)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=1, max_len=32,
                              paged=True, page_size=8)
         finished = engine.run([Request(rid=0, arrival=0, prompt=prompt,
@@ -419,7 +419,7 @@ def test_paged_max_len_truncation_edge():
     cfg, params, mesh = _setup()
     rng = np.random.default_rng(7)
     p12, p15 = _prompt(rng, cfg, 12), _prompt(rng, cfg, 15)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=2, max_len=16,
                              paged=True, page_size=8)
         finished = engine.run(
@@ -437,7 +437,7 @@ def test_paged_matches_dense_on_every_named_stream():
     heavy-tail giants.  One engine pair reused across streams (reset
     between) keeps the compile bill to one set of executables."""
     cfg, params, mesh = _setup()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         dense = ServeEngine(cfg, params, slots=3, max_len=64)
         paged = ServeEngine(cfg, params, slots=3, max_len=64,
                             paged=True, page_size=8)
@@ -460,7 +460,7 @@ def test_paged_shared_prefix_stream_hits_and_parity():
     ps = 8
     reqs = lambda: build_stream("bursty", 10, vocab=cfg.vocab_size, seed=13,
                                 prompt_max=20, out_max=6, shared_prefix=20)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         dense = ServeEngine(cfg, params, slots=3, max_len=96)
         want = {r.rid: r.out for r in dense.run(reqs(), log=None)}
         paged = ServeEngine(cfg, params, slots=3, max_len=96,
@@ -490,7 +490,7 @@ def test_paged_prefill_compile_count(trace_guard):
         return [Request(rid=i, arrival=3 * i, prompt=_prompt(rng, cfg, n),
                         max_new=2)
                 for i, n in enumerate(lengths)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         engine = ServeEngine(cfg, params, slots=2, max_len=80,
                              paged=True, page_size=16)
         with trace_guard(engine._admit_fn, max_compiles=bound):
@@ -512,7 +512,7 @@ def test_paged_undersized_pool_defers_and_stays_exact():
     max_len, ps = 32, 8
     reqs = [Request(rid=i, arrival=0, prompt=_prompt(rng, cfg, 10), max_new=4)
             for i in range(4)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         want = {r.rid: sequential_decode(cfg, params, r.prompt, r.max_new,
                                          max_len) for r in reqs}
         # 2 pages/slot needed (10 prompt + 3 decode = 13 positions); grant
@@ -539,7 +539,7 @@ def test_paged_resident_cache_reduction():
     rng = np.random.default_rng(41)
     reqs = lambda: [Request(rid=i, arrival=i, prompt=_prompt(rng, cfg, 6),
                             max_new=4) for i in range(6)]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         dense = ServeEngine(cfg, params, slots=4, max_len=128)
         dense.run(reqs(), log=None)
         paged = ServeEngine(cfg, params, slots=4, max_len=128,
@@ -554,7 +554,7 @@ def test_paged_resident_cache_reduction():
 def test_paged_rejects_unpageable_archs():
     cfg = registry.get_smoke_config("mamba2-370m")
     mesh = make_test_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Transformer.init(cfg, jax.random.key(0))
         with pytest.raises(ValueError, match="paged"):
             ServeEngine(cfg, params, slots=1, max_len=16, paged=True)
@@ -574,7 +574,7 @@ def test_vectorized_pos_decode_matches_scalar(ring):
     positions = [2, 5, 9]
     key = jax.random.key(21)
     toks = jax.random.randint(key, (b, 1), 0, cfg.vocab_size - 1)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         caches = Transformer.init_cache(cfg, b, max_len)
         # seed caches with random (but shared) content so masks matter
         caches = jax.tree.map(

@@ -158,7 +158,9 @@ def test_paged_gather_matches_dense_reference(seed, ps, pp, window):
             kp[pages[p // ps], p % ps] = seq_k[p]
             vp[pages[p // ps], p % ps] = seq_v[p]
         seqs.append((seq_k, seq_v))
-    got = paged_decode(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+    # The kernel's pools: one layer, each page's heads side by side.
+    stack = lambda a: jnp.asarray(a.reshape(1, num_pages, ps, n * d))
+    got = paged_decode(jnp.asarray(q), stack(kp), stack(vp),
                        jnp.asarray(pt), jnp.asarray(pos), window=window,
                        interpret=True)
     for i in range(b):
