@@ -16,7 +16,7 @@ def main() -> None:
     fast = "--fast" in sys.argv
     rounds = 3 if fast else 5
     from benchmarks import fig4_main, fig5_forget, fig7_aggregation, \
-        fig9_straggler, kernels_bench
+        fig9_straggler
 
     print("# fig4 — main R=1 comparison (paper Fig. 4)")
     _, c4 = fig4_main.main(rounds=rounds)
@@ -26,8 +26,6 @@ def main() -> None:
     _, c7 = fig7_aggregation.main(rounds=max(rounds - 1, 2))
     print("# fig9/11 — straggler robustness (paper Figs. 9 & 11)")
     _, c9 = fig9_straggler.main(rounds=rounds + 1)
-    print("# kernels — fused KD loss / RG-LRU / SSD")
-    kernels_bench.main()
 
     if os.path.isdir("experiments/dryrun"):
         print("# roofline — from the multi-pod dry-run (EXPERIMENTS.md §Roofline)")

@@ -263,6 +263,7 @@ def kd_stats_fwd(labels, s, t, b, tau, *, interpret=False):
                                with_buffer=with_buffer)
     return pl.pallas_call(
         kernel,
+        name="kd_loss_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
@@ -289,6 +290,7 @@ def kd_grad_bwd(labels, g, stats, s, t, b, tau, mean_scale, *, interpret=False):
                                with_buffer=with_buffer, mean_scale=float(mean_scale))
     return pl.pallas_call(
         kernel,
+        name="kd_loss_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
@@ -321,6 +323,7 @@ def kd_quant_stats_fwd(labels, s, codes, scale, zero, b, tau, vocab, *,
                                vocab=int(vocab))
     return pl.pallas_call(
         kernel,
+        name="kd_loss_quant_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),
@@ -353,6 +356,7 @@ def kd_quant_grad_bwd(labels, g, stats, s, codes, scale, zero, b, tau, vocab,
                                vocab=int(vocab))
     return pl.pallas_call(
         kernel,
+        name="kd_loss_quant_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((rb, 1), lambda i, j: (i, 0)),

@@ -165,6 +165,7 @@ def paged_decode(q, k_pool, v_pool, pt, pos, *, layer=0, window=None,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n, g, d), q.dtype),
         interpret=interpret,

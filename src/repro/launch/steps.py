@@ -11,6 +11,13 @@ frozen teacher + frozen buffer forwards, chunked big-vocab loss (Eqs. 3/4).
 ensemble distillation; the DistillMethod registry's LLM hints
 (`llm_buffer` / `llm_ce_weight`) pick these knobs per method.
 
+The step's parts run under named scopes (``jax.named_scope``: HLO
+metadata only, the compiled ops are the same), so a device trace can put
+each op down to its part: ``p2_student`` (the student's forward, and its
+backward and remat recompute, which carry the scope inside
+``transpose(jvp(...))``), ``p2_teacher``, ``p2_buffer``, ``p2_kd_loss``
+(the chunked loss, logits included) and ``p2_adamw``.
+
 `make_pretrain_step` is Phase 0/1 (plain CE).  The inference steps
 (`make_serve_step` / `make_prefill_step`) moved to `repro.serve.engine`
 with the serving subsystem; they are re-exported here for the dry-run and
@@ -143,27 +150,33 @@ def make_phase2_step(cfg: LMConfig, opt, *, tau=2.0, buffer_mode="clone",
         ("cached"), or ignored ("none")."""
 
         def loss_fn(params):
-            h_s, aux = Transformer.apply_hidden(cfg, params, batch)
-            h_t, _ = Transformer.apply_hidden(cfg, teacher, batch)
-            h_t = jax.lax.stop_gradient(h_t)
+            with jax.named_scope("p2_student"):
+                h_s, aux = Transformer.apply_hidden(cfg, params, batch)
+            with jax.named_scope("p2_teacher"):
+                h_t, _ = Transformer.apply_hidden(cfg, teacher, batch)
+                h_t = jax.lax.stop_gradient(h_t)
             h_b = None
             cached = None
             if buffer_mode == "clone":
-                h_b, _ = Transformer.apply_hidden(cfg, buffer_arg, batch)
-                h_b = jax.lax.stop_gradient(h_b)
+                with jax.named_scope("p2_buffer"):
+                    h_b, _ = Transformer.apply_hidden(cfg, buffer_arg, batch)
+                    h_b = jax.lax.stop_gradient(h_b)
             elif buffer_mode == "cached":
                 cached = buffer_arg
-            loss = _chunked_bkd_loss(cfg, params, teacher,
-                                     buffer_arg if buffer_mode == "clone" else None,
-                                     batch, h_s, h_t, h_b, tau, loss_chunk,
-                                     cached_buffer_logits=cached, topk=topk,
-                                     loss_backend=loss_backend,
-                                     ce_weight=ce_weight,
-                                     teacher_transform=teacher_transform)
+            with jax.named_scope("p2_kd_loss"):
+                loss = _chunked_bkd_loss(
+                    cfg, params, teacher,
+                    buffer_arg if buffer_mode == "clone" else None,
+                    batch, h_s, h_t, h_b, tau, loss_chunk,
+                    cached_buffer_logits=cached, topk=topk,
+                    loss_backend=loss_backend, ce_weight=ce_weight,
+                    teacher_transform=teacher_transform)
             return loss + aux_weight * aux, loss
 
         (total, kd_loss), grads = jax.value_and_grad(loss_fn, has_aux=True)(student)
-        new_params, new_opt = opt.update(grads, opt_state, student, step_idx)
+        with jax.named_scope("p2_adamw"):
+            new_params, new_opt = opt.update(grads, opt_state, student,
+                                             step_idx)
         return new_params, new_opt, {"loss": total, "kd_loss": kd_loss}
 
     return step

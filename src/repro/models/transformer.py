@@ -13,6 +13,10 @@ configs); a remainder (num_layers % len(pattern)) is unrolled as a tail.
 All parameters carry logical sharding axes (see repro/sharding/rules.py).
 `Transformer.init` runs under `jax.eval_shape` for the allocation-free
 dry-run path.
+
+The attention mixers, the MLPs and the unembedding run under the named
+scopes `attention`, `mlp` and `unembed` (HLO metadata only), so a device
+trace can put an op down to its layer.
 """
 
 from __future__ import annotations
@@ -209,13 +213,15 @@ def _mlp_apply(cfg: LMConfig, p, x):
     """Returns (y, aux_loss)."""
     if cfg.mlp == "none":
         return jnp.zeros_like(x), 0.0
-    if cfg.mlp == "moe":
-        y, aux = moe_lib.forward({k: v for k, v in p.items() if k != "shared"},
-                                 cfg.moe_cfg(), x)
-        if "shared" in p:
-            y = y + _dense_mlp_apply(cfg, p["shared"], x)
-        return y, aux
-    return _dense_mlp_apply(cfg, p, x), 0.0
+    with jax.named_scope("mlp"):
+        if cfg.mlp == "moe":
+            y, aux = moe_lib.forward(
+                {k: v for k, v in p.items() if k != "shared"}, cfg.moe_cfg(),
+                x)
+            if "shared" in p:
+                y = y + _dense_mlp_apply(cfg, p["shared"], x)
+            return y, aux
+        return _dense_mlp_apply(cfg, p, x), 0.0
 
 
 def _subblock_init(cfg: LMConfig, kind: str, key, stack=None):
@@ -239,7 +245,9 @@ def _subblock_init(cfg: LMConfig, kind: str, key, stack=None):
 
 def _mixer_apply(cfg: LMConfig, kind: str, p, x, positions):
     if kind in ("attn", "local"):
-        return attn_lib.forward(p, cfg.attn_cfg(kind == "local"), x, positions)
+        with jax.named_scope("attention"):
+            return attn_lib.forward(p, cfg.attn_cfg(kind == "local"), x,
+                                    positions)
     if kind == "ssd":
         return ssm_lib.forward(p, cfg.ssd_cfg(), x)
     if kind == "rglru":
@@ -427,13 +435,14 @@ class Transformer:
 
     @staticmethod
     def _unembed(cfg: LMConfig, params, x):
-        x = _norm_apply(cfg, params["ln_f"], x)
-        logits = L.dense(params["unembed"], x).astype(jnp.float32)
-        logits = constrain(logits, ("batch", None, "vocab"))
-        if cfg.padded_vocab != cfg.vocab_size:
-            pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-            logits = jnp.where(pad_mask, logits, NEG_INF)
-        return logits
+        with jax.named_scope("unembed"):
+            x = _norm_apply(cfg, params["ln_f"], x)
+            logits = L.dense(params["unembed"], x).astype(jnp.float32)
+            logits = constrain(logits, ("batch", None, "vocab"))
+            if cfg.padded_vocab != cfg.vocab_size:
+                pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+                logits = jnp.where(pad_mask, logits, NEG_INF)
+            return logits
 
     # -- full-sequence forward (train / prefill trunk) ----------------------
 

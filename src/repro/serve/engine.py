@@ -31,6 +31,22 @@ first token emitted from the prefill's own last-real-position logits)
 -> free.  Inactive slots ride along in the batch with their state frozen
 by ``where(active, ...)`` masks — their cache writes are idempotent junk at
 a stale position that the next admission overwrites wholesale.
+
+Instrumentation: each tick's host work runs under named profiler spans
+(``jax.profiler.TraceAnnotation``, recorded only while a profiler trace is
+active) that share the device trace's clock:
+
+  serve.tick            the whole :meth:`ServeEngine.tick`
+    serve.schedule      eligibility stamps, free-slot choice, page grants
+    serve.admit         one admission group (args: rows, bucket, npp)
+      serve.admit.prep  host prompt arrays and their upload
+      serve.admit.sync  the pull of (first token, done)
+    serve.decode        the decode tick's dispatch
+    serve.decode.sync   the pull of (emitted, done)
+    serve.emit          per-slot token append, finish and page release
+
+:meth:`ServeEngine.counters` gives the host loop's own counts since
+:meth:`ServeEngine.reset` with the prefix cache's beside them.
 """
 
 from __future__ import annotations
@@ -40,6 +56,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.models.transformer import LMConfig, Transformer
 from repro.serve.paged import PageAllocator, Admission, TRASH_PAGE, pages_for
@@ -71,6 +88,16 @@ def make_prefill_step(cfg: LMConfig, max_len):
         return nxt, cache
 
     return step
+
+
+#: The host loop's counters (:meth:`ServeEngine.counters`): decode ticks,
+#: active rows summed over them, admission groups, requests admitted, the
+#: padded rows those groups prefilled (slots x bucket) and the prompt
+#: tokens among them that were not served from the prefix cache.
+LOOP_COUNTERS = ("decode_ticks", "decode_rows", "admissions", "admitted",
+                 "admit_rows", "admit_tokens")
+PREFIX_COUNTERS = ("hits", "misses", "evictions", "peak_pages",
+                   "pages_in_use")
 
 
 def make_sampler(sample: str = "greedy", temperature: float = 1.0,
@@ -345,6 +372,7 @@ class ServeEngine:
              self.active) = self._init_fn()
         self._host_active = [None] * self.slots   # slot -> Request | None
         self.ticks = 0
+        self._counts = dict.fromkeys(LOOP_COUNTERS, 0)
         # restart the sampling stream too: a reset engine must reproduce a
         # fresh ServeEngine(seed=...) under stochastic sampling
         self._key = jax.random.key(self._seed)
@@ -433,14 +461,24 @@ class ServeEngine:
         pages = self._alloc.peak if peak else self._alloc.in_use
         return pages * self.cache_page_bytes()
 
+    def counters(self) -> dict:
+        """Every counter since :meth:`reset`: the host loop's
+        (:data:`LOOP_COUNTERS`) and the prefix cache's
+        (:data:`PREFIX_COUNTERS`, zeros on the dense engine).  Plain ints,
+        counted on the host: reading them costs no device sync."""
+        out = dict(self._counts)
+        if self.paged:
+            a = self._alloc
+            out.update(hits=a.hits, misses=a.misses, evictions=a.evictions,
+                       peak_pages=a.peak, pages_in_use=a.in_use)
+        else:
+            out.update(dict.fromkeys(PREFIX_COUNTERS, 0))
+        return out
+
     def prefix_stats(self) -> dict:
-        """Prefix-cache counters (zeros on the dense engine)."""
-        if not self.paged:
-            return {"hits": 0, "misses": 0, "evictions": 0,
-                    "peak_pages": 0, "pages_in_use": 0}
-        a = self._alloc
-        return {"hits": a.hits, "misses": a.misses, "evictions": a.evictions,
-                "peak_pages": a.peak, "pages_in_use": a.in_use}
+        """The prefix-cache part of :meth:`counters`."""
+        c = self.counters()
+        return {k: c[k] for k in PREFIX_COUNTERS}
 
     def _next_key(self):
         if not self._stochastic:
@@ -456,32 +494,50 @@ class ServeEngine:
         length-homogeneous, on the recurrent path).  ``params`` is the
         tick's single params snapshot — admission and decode within one
         tick always share a version."""
-        s = self.slots
         length = (self._bucket(max(len(r.prompt) for _, r in group))
                   if self._bucketed else max(len(r.prompt) for _, r in group))
+        with _span("serve.admit", rows=len(group), bucket=length, npp=0):
+            with _span("serve.admit.prep"):
+                args = self._admit_args(group, length, 0)
+            t_admit = time.perf_counter()
+            (self.tokens, self.caches, self.pos, self.budget, self.active,
+             first, done_now) = self._admit_fn(
+                params, self.caches, self.tokens, self.pos, self.budget,
+                self.active, *args, self._next_key())
+            self._post_admit(group, first, done_now, now, length, t_admit,
+                             log)
+
+    def _admit_args(self, group, length, start):
+        """The admission's device inputs (prompts, lengths, max_news,
+        fill) at the full slot batch: each request's prompt from token
+        ``start`` on, right-padded to ``length``.  Counts the admission."""
+        s = self.slots
         prompts = np.zeros((s, length), np.int32)
         lengths = np.ones((s,), np.int32)
         max_news = np.ones((s,), np.int32)
         fill = np.zeros((s,), bool)
         for slot, req in group:
-            plen = len(req.prompt)
-            prompts[slot, :plen] = req.prompt
-            lengths[slot], max_news[slot], fill[slot] = plen, req.max_new, True
-        (self.tokens, self.caches, self.pos, self.budget, self.active,
-         first, done_now) = self._admit_fn(
-            params, self.caches, self.tokens, self.pos, self.budget,
-            self.active, jnp.asarray(prompts), jnp.asarray(lengths),
-            jnp.asarray(max_news), jnp.asarray(fill), self._next_key())
-        self._post_admit(group, first, done_now, now, length, log)
+            sl = len(req.prompt) - start
+            prompts[slot, :sl] = req.prompt[start:]
+            lengths[slot], max_news[slot], fill[slot] = sl, req.max_new, True
+        c = self._counts
+        c["admissions"] += 1
+        c["admitted"] += len(group)
+        c["admit_rows"] += s * length
+        c["admit_tokens"] += int(lengths[fill].sum())
+        return (jnp.asarray(prompts), jnp.asarray(lengths),
+                jnp.asarray(max_news), jnp.asarray(fill))
 
-    def _post_admit(self, group, first, done_now, now, length, log):
+    def _post_admit(self, group, first, done_now, now, length, t_admit, log):
         """Shared admission epilogue: pull (first, done) once, stamp the
         requests, finish the already-done slots."""
-        first_np, done_np = jax.device_get((first, done_now))
+        with _span("serve.admit.sync"):
+            first_np, done_np = jax.device_get((first, done_now))
         t_wall = time.perf_counter()
         for slot, req in group:
             req.out.append(int(first_np[slot]))
             req.admitted_at = now
+            req.t_admit = t_admit
             req.t_first = t_wall
             self._host_active[slot] = req
             if log:
@@ -490,12 +546,28 @@ class ServeEngine:
             if done_np[slot]:
                 self._finish(slot, now, t_wall, log)
 
-    def _admit_paged(self, params, batch, now, log):
-        """Paged admission: grant pages (consulting the prefix cache) per
-        request, then run one batched prefill per prefix-hit depth —
-        shallower groups first, so a same-tick deeper hit gathers pages a
-        shallower admission's scatter just wrote.  If the pool cannot
-        grant a request's pages even after eviction (only possible with an
+    def _groups(self, batch):
+        """This tick's admission groups as [(npp, [(slot, request)])], in
+        the order they run.  Bucketed: one group at the largest arrival's
+        bucket (padding is numerically invisible, so splitting same-tick
+        arrivals per bucket would only run extra full-slot-batch
+        prefills).  Recurrent: one group per exact prompt length (rows
+        cannot be padded).  Paged: :meth:`_grant_pages`."""
+        if self.paged:
+            return self._grant_pages(batch)
+        if self._bucketed:
+            return [(0, batch)]
+        groups = {}
+        for slot, req in batch:
+            groups.setdefault(len(req.prompt), []).append((slot, req))
+        return [(0, g) for _, g in sorted(groups.items())]
+
+    def _grant_pages(self, batch):
+        """Paged scheduling: grant pages (consulting the prefix cache) per
+        request and group the requests by prefix-hit depth, shallower
+        groups first, so a same-tick deeper hit gathers pages a shallower
+        admission's scatter just wrote.  If the pool cannot grant a
+        request's pages even after eviction (only possible with an
         explicitly undersized pool), it and everything behind it requeue —
         FIFO order is preserved."""
         groups = {}
@@ -513,34 +585,27 @@ class ServeEngine:
             groups.setdefault(adm.shared, []).append((slot, req))
         if requeue:
             self._queue[:0] = requeue
-        if not groups:
-            return
-        self.pt = jnp.asarray(self._pt_host)
-        for npp in sorted(groups):
-            self._admit_group_paged(params, groups[npp], npp, now, log)
+        return sorted(groups.items())
 
-    def _admit_group_paged(self, params, group, npp, now, log):
+    def _admit_group_paged(self, params, group, npp, now, log, upload_pt):
         """One batched paged admission at prefix-hit depth ``npp``: rows
         carry the prompt *suffixes* (everything past the shared pages),
-        right-padded to the suffix length bucket."""
-        s = self.slots
+        right-padded to the suffix length bucket.  The tick's first group
+        uploads the page table its grants wrote."""
         start = npp * self.page_size
         length = self._bucket(max(len(r.prompt) - start for _, r in group))
-        prompts = np.zeros((s, length), np.int32)
-        lengths = np.ones((s,), np.int32)
-        max_news = np.ones((s,), np.int32)
-        fill = np.zeros((s,), bool)
-        for slot, req in group:
-            sl = len(req.prompt) - start
-            prompts[slot, :sl] = req.prompt[start:]
-            lengths[slot], max_news[slot], fill[slot] = sl, req.max_new, True
-        (self.tokens, self.caches, self.pos, self.budget, self.active,
-         first, done_now) = self._admit_fn(
-            params, self.caches, self.pt, self.tokens, self.pos, self.budget,
-            self.active, jnp.asarray(prompts), jnp.asarray(lengths),
-            jnp.asarray(max_news), jnp.asarray(fill), self._next_key(),
-            npp=npp)
-        self._post_admit(group, first, done_now, now, length, log)
+        with _span("serve.admit", rows=len(group), bucket=length, npp=npp):
+            with _span("serve.admit.prep"):
+                args = self._admit_args(group, length, start)
+                if upload_pt:
+                    self.pt = jnp.asarray(self._pt_host)
+            t_admit = time.perf_counter()
+            (self.tokens, self.caches, self.pos, self.budget, self.active,
+             first, done_now) = self._admit_fn(
+                params, self.caches, self.pt, self.tokens, self.pos,
+                self.budget, self.active, *args, self._next_key(), npp=npp)
+            self._post_admit(group, first, done_now, now, length, t_admit,
+                             log)
 
     def _finish(self, slot, now, t_wall, log):
         req = self._host_active[slot]
@@ -603,62 +668,65 @@ class ServeEngine:
 
         ``self.params`` is read once at entry; :meth:`commit_swap` between
         ticks is therefore atomic — no tick mixes params versions."""
+        with _span("serve.tick"):
+            return self._tick()
+
+    def _tick(self):
         params = self.params     # the tick's single params-version read
         log, now, base = self._log, self._now, self._base
         queue = self._queue
         n_done = len(self._finished)
-        # Stamp queue-eligibility (TTFT clock starts here, not at
-        # admission — queueing delay is part of time-to-first-token).
-        t_wall = time.perf_counter()
-        for r in queue:
-            if r.arrival + base <= now and r.t_enqueue < 0:
-                r.t_enqueue = t_wall
-            elif r.arrival + base > now:
-                break
-        # Admit eligible arrivals into free slots, grouped by bucket.
-        free = [s for s in range(self.slots)
-                if self._host_active[s] is None]
-        batch = []
-        while free and queue and queue[0].arrival + base <= now:
-            batch.append((free.pop(0), queue.pop(0)))
-        if self.paged and batch:
-            self._admit_paged(params, batch, now, log)
-        elif self._bucketed and batch:
-            # One admission per tick at the largest arrival's bucket:
-            # padding is numerically invisible (lengths= masks it), so
-            # splitting same-tick arrivals per bucket would only run
-            # extra full-slot-batch prefills.
-            self._admit_group(params, batch, now, log)
-        else:
-            # Recurrent (exact-length) admission: rows cannot be
-            # padded, so groups must share one exact prompt length.
-            groups = {}
-            for slot, req in batch:
-                groups.setdefault(len(req.prompt), []).append((slot, req))
-            for _, group in sorted(groups.items()):
+        with _span("serve.schedule"):
+            # Stamp queue-eligibility (TTFT clock starts here, not at
+            # admission — queueing delay is part of time-to-first-token).
+            t_wall = time.perf_counter()
+            for r in queue:
+                if r.arrival + base <= now and r.t_enqueue < 0:
+                    r.t_enqueue = t_wall
+                elif r.arrival + base > now:
+                    break
+            # Admit eligible arrivals into free slots, in groups.
+            free = [s for s in range(self.slots)
+                    if self._host_active[s] is None]
+            batch = []
+            while free and queue and queue[0].arrival + base <= now:
+                batch.append((free.pop(0), queue.pop(0)))
+            groups = self._groups(batch) if batch else []
+        for i, (npp, group) in enumerate(groups):
+            if self.paged:
+                self._admit_group_paged(params, group, npp, now, log,
+                                        upload_pt=i == 0)
+            else:
                 self._admit_group(params, group, now, log)
         if any(r is not None for r in self._host_active):
             # One decode tick for every slot; one host sync.
-            if self.paged:
-                (self.tokens, self.caches, self.pos, self.budget,
-                 self.active, done) = self._tick_fn(
-                    params, self.caches, self.pt, self.tokens, self.pos,
-                    self.budget, self.active, self._next_key())
-            else:
-                (self.tokens, self.caches, self.pos, self.budget,
-                 self.active, done) = self._tick_fn(
-                    params, self.caches, self.tokens, self.pos, self.budget,
-                    self.active, self._next_key())
-            # reprolint: disable=R002 (one sync per tick IS the contract)
-            emitted_np, done_np = jax.device_get((self.tokens, done))
-            t_wall = time.perf_counter()
-            for s in range(self.slots):
-                req = self._host_active[s]
-                if req is None:
-                    continue
-                req.out.append(int(emitted_np[s, 0]))
-                if done_np[s]:
-                    self._finish(s, now, t_wall, log)
+            with _span("serve.decode"):
+                if self.paged:
+                    (self.tokens, self.caches, self.pos, self.budget,
+                     self.active, done) = self._tick_fn(
+                        params, self.caches, self.pt, self.tokens, self.pos,
+                        self.budget, self.active, self._next_key())
+                else:
+                    (self.tokens, self.caches, self.pos, self.budget,
+                     self.active, done) = self._tick_fn(
+                        params, self.caches, self.tokens, self.pos,
+                        self.budget, self.active, self._next_key())
+            with _span("serve.decode.sync"):
+                # reprolint: disable=R002 (one sync per tick IS the contract)
+                emitted_np, done_np = jax.device_get((self.tokens, done))
+            with _span("serve.emit"):
+                t_wall = time.perf_counter()
+                rows = 0
+                for s in range(self.slots):
+                    req = self._host_active[s]
+                    if req is None:
+                        continue
+                    rows += 1
+                    req.out.append(int(emitted_np[s, 0]))
+                    if done_np[s]:
+                        self._finish(s, now, t_wall, log)
+            self._counts["decode_ticks"] += 1
+            self._counts["decode_rows"] += rows
         self._now = now + 1
         self.ticks = self._now
         return self._finished[n_done:]

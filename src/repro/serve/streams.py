@@ -34,7 +34,10 @@ class Request:
     engine stamps ``admitted_at``/``done_at`` (ticks) and
     ``t_enqueue``/``t_first``/``t_done`` (host wall-clock seconds) as the
     request moves through the slot lifecycle — the raw material for
-    time-to-first-token and inter-token latency percentiles."""
+    time-to-first-token and inter-token latency percentiles.  ``t_admit``
+    is the host time at which the request's admission group started its
+    dispatch: ``t_admit - t_enqueue`` is its wait in the queue and
+    ``t_first - t_admit`` its admission."""
 
     rid: int
     arrival: int
@@ -44,6 +47,7 @@ class Request:
     admitted_at: int = -1
     done_at: int = -1
     t_enqueue: float = -1.0
+    t_admit: float = -1.0
     t_first: float = -1.0
     t_done: float = -1.0
     prefix_pages: int = 0    # pages served from the paged engine's prefix

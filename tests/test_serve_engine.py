@@ -596,3 +596,40 @@ def test_vectorized_pos_decode_matches_scalar(ring):
             np.testing.assert_allclose(np.asarray(lg_vec[i]),
                                        np.asarray(lg_one[0]),
                                        rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# Host-loop counters and lifecycle stamps.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_counters_and_stamps_account_for_every_request(paged):
+    """The engine's counters add up to what its requests record: every
+    request admitted once, its suffix tokens prefilled, one decode row per
+    token after the first; ``t_enqueue <= t_admit <= t_first <= t_done``.
+    ``prefix_stats`` is the prefix part of ``counters``; ``reset`` zeroes
+    them."""
+    cfg, params, mesh = _setup()
+    ps = 8
+    reqs = build_stream("bursty", 8, vocab=cfg.vocab_size, seed=5,
+                        prompt_max=20, out_max=6, shared_prefix=16)
+    with jax.set_mesh(mesh):
+        engine = ServeEngine(cfg, params, slots=3, max_len=64, paged=paged,
+                             page_size=ps)
+        finished = engine.run(reqs, log=None)
+    c = engine.counters()
+    assert len(finished) == len(reqs) == c["admitted"]
+    assert 1 <= c["admissions"] <= c["admitted"]
+    assert c["admit_tokens"] == sum(len(r.prompt) - r.prefix_pages * ps
+                                    for r in finished)
+    assert c["admit_tokens"] <= c["admit_rows"]
+    assert c["decode_rows"] == sum(len(r.out) - 1 for r in finished)
+    assert c["decode_rows"] <= c["decode_ticks"] * engine.slots
+    assert c["decode_ticks"] <= engine.ticks
+    for r in finished:
+        assert 0 < r.t_enqueue <= r.t_admit <= r.t_first <= r.t_done
+    assert engine.prefix_stats() == {k: c[k] for k in engine.prefix_stats()}
+    assert (c["hits"] > 0) == paged
+    engine.reset()
+    assert not any(engine.counters().values())
