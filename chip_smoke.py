@@ -4,8 +4,9 @@ on a TPU, at the published widths of granite-3-2b.
 One process takes the chip once, spawns no children, and runs in order:
 
   device   platform, kind and count as JAX reports them; fails off a TPU
-  kernels  paged_decode and kd_loss (value and gradient) on the chip at the
-           shapes the phases below use, against repro.kernels.ref
+  kernels  paged_decode (at granite's and qwen1.5-4b's head shapes) and
+           kd_loss (value and gradient) on the chip at the shapes the
+           phases below use, against repro.kernels.ref
   serve    repro.launch.serve --full --paged: all 40 layers, a shared
            system prefix, every request served to its requested length
   phase2   buffered-KD steps (clone buffer, fused Pallas loss) through
@@ -95,7 +96,9 @@ def phase_device(need):
 # ---------------------------------------------------------------------------
 
 def check_paged_decode(cfg, slots, max_len, page_size):
-    """paged_decode on the device vs the dense-view reference."""
+    """paged_decode on the device vs the dense-view reference: ``slots``
+    rows from a full table down to a few keys, then a row of a single page
+    and an inactive row (``pos = -1``, which must come back zeros)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -103,15 +106,17 @@ def check_paged_decode(cfg, slots, max_len, page_size):
 
     n, d = cfg.kv_heads, cfg.head_dim
     g = cfg.num_heads // n
+    rows = slots + 2
     pp = max_len // page_size
-    pages = slots * pp + 1                       # + the trash page
+    pages = rows * pp + 1                        # + the trash page
     kq, kk, kv, kp = jax.random.split(jax.random.key(SEED), 4)
-    q = jax.random.normal(kq, (slots, n, g, d), jnp.bfloat16)
+    q = jax.random.normal(kq, (rows, n, g, d), jnp.bfloat16)
     k_pool = jax.random.normal(kk, (pages, page_size, n * d), jnp.bfloat16)
     v_pool = jax.random.normal(kv, (pages, page_size, n * d), jnp.bfloat16)
-    pt = (1 + jax.random.permutation(kp, pages - 1)).reshape(slots, pp)
+    pt = (1 + jax.random.permutation(kp, pages - 1)).reshape(rows, pp)
     pt = pt.astype(jnp.int32)
-    pos = jnp.asarray(np.linspace(max_len - 1, 3, slots).astype(np.int32))
+    pos = np.linspace(max_len - 1, 3, slots).astype(np.int32)
+    pos = jnp.asarray(np.concatenate([pos, [page_size // 2, -1]]), jnp.int32)
     got = jax.jit(lambda *a: ops.paged_decode_attn(*a, use_pallas=True))(
         q, k_pool, v_pool, pt, pos)
     f32 = lambda a: a.astype(jnp.float32)
@@ -119,10 +124,13 @@ def check_paged_decode(cfg, slots, max_len, page_size):
         want = jax.jit(ref.paged_decode_ref)(f32(q), f32(k_pool), f32(v_pool),
                                              pt, pos)
     err = float(jnp.max(jnp.abs(f32(got) - want)))
+    idle = float(jnp.max(jnp.abs(f32(got[-1]))))
     log(f"[kernels] paged_decode q{tuple(q.shape)} pool{tuple(k_pool.shape)} "
-        f"bf16: max |err| {err} (atol {PAGED_ATOL})")
+        f"bf16 pos {pos.tolist()}: max |err| {err} (atol {PAGED_ATOL}), "
+        f"inactive row max |out| {idle}")
     check(got.shape == q.shape and err <= PAGED_ATOL,
           f"paged_decode off the reference by {err}")
+    check(idle == 0.0, f"paged_decode's inactive row is not zeros ({idle})")
 
 
 def check_kd_loss(rows, vocab, valid):
@@ -161,6 +169,9 @@ def phase_kernels():
     cfg = registry.get_config(ARCH)
     t0 = time.perf_counter()
     check_paged_decode(cfg, SLOTS, MAX_LEN, PAGE_SIZE)
+    # One query row per kv head (G = 1), as the decode-backlog cell serves.
+    check_paged_decode(registry.get_config("qwen1.5-4b"), SLOTS, 1024,
+                       PAGE_SIZE)
     check_kd_loss(P2_BATCH * LOSS_CHUNK, cfg.padded_vocab, cfg.vocab_size)
     log(f"[kernels] ok in {time.perf_counter() - t0:.3f}s")
 

@@ -249,10 +249,10 @@ def paged_decode_attn(q, k_pool, v_pool, pt, pos, *, layer=None, window=None,
     """Block-paged decode attention (the paged ServeEngine's tick hot spot).
     q: (B, N, G, D); k/v_pool: (P, page_size, N*D), or (L, P, page_size,
     N*D) stacked over layers with ``layer`` picking one; pt: (B, PP) int32
-    page table; pos: (B,) int32.  The pallas path gathers pages inside the
-    kernel's index maps (scalar-prefetched page table and layer), reading
-    the pool in place; the reference path materializes the dense per-slot
-    view.
+    page table; pos: (B,) int32, ``pos < 0`` for an inactive row (zeros
+    out).  The pallas path copies each active row's own pages out of the
+    pool in place (scalar-prefetched page table, positions and layer); the
+    reference path materializes the dense per-slot view.
 
     Under a mesh the kernel runs in a ``shard_map``: Mosaic kernels are not
     auto-partitioned, and XLA would otherwise gather the whole pool onto

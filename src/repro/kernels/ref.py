@@ -51,7 +51,8 @@ def paged_decode_ref(q, k_pool, v_pool, pt, pos, window=None):
     """Paged-cache decode attention oracle: gather every slot's pages into
     a dense (B, PP*ps, N, D) view and run the masked softmax.  q:
     (B, N, G, D); pools (P, ps, N*D); pt (B, PP) int32; pos (B,) int32.
-    Positions past ``pos`` (including trash-page placeholders) are masked."""
+    Positions past ``pos`` (including trash-page placeholders) are masked;
+    a row with ``pos < 0`` is inactive and attends nothing: zeros."""
     b, n, g, d = q.shape
     ps = k_pool.shape[1]
     pp = pt.shape[1]
@@ -66,8 +67,8 @@ def paged_decode_ref(q, k_pool, v_pool, pt, pos, window=None):
                    kc.astype(jnp.float32)) / jnp.sqrt(float(d))
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bngw,bwnd->bngd", p,
-                      vc.astype(jnp.float32)).astype(q.dtype)
+    out = jnp.einsum("bngw,bwnd->bngd", p, vc.astype(jnp.float32))
+    return jnp.where(p_col[:, :, None, None] >= 0, out, 0).astype(q.dtype)
 
 
 def swa_decode_ref(q, k_cache, v_cache, pos, window=None, ring=False):

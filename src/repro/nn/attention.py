@@ -310,9 +310,11 @@ def paged_decode_step(params, cfg: AttnConfig, pool, pt, x, pos, positions=None,
     stacked pools copies no pool.  Rows with ``active`` False write their
     K/V to the trash page — a freed slot's stale table may point at pages
     since reallocated to another slot, so unlike the dense cache its junk
-    writes must be *redirected*, not merely overwritten later.  The
-    attention gather runs through :func:`repro.kernels.ops.paged_decode_attn`
-    (Pallas on TPU, dense-view reference elsewhere)."""
+    writes must be *redirected*, not merely overwritten later, and they
+    attend nothing: the kernel gets ``pos = -1`` for them, reads none of
+    their pages and returns zeros.  The attention gather runs through
+    :func:`repro.kernels.ops.paged_decode_attn` (Pallas on TPU, dense-view
+    reference elsewhere)."""
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     if positions is None:
@@ -322,13 +324,15 @@ def paged_decode_step(params, cfg: AttnConfig, pool, pt, x, pos, positions=None,
     q, k, v = _project_qkv(params, cfg, x, positions)
     rows = jnp.arange(b)
     page = pt[rows, pos // page_size]
+    attend_pos = pos
     if active is not None:
         page = jnp.where(active, page, 0)
+        attend_pos = jnp.where(active, pos, -1)
     off = pos % page_size
     pk, pv = _write_pages(pool, layer, page, off, k[:, 0], v[:, 0])
     from repro.kernels import ops  # local import: kernels must not be a hard dep of nn
     qg = q[:, 0].reshape(b, cfg.kv_heads, cfg.q_groups, cfg.head_dim)
-    out = ops.paged_decode_attn(qg, pk, pv, pt, pos, layer=layer,
+    out = ops.paged_decode_attn(qg, pk, pv, pt, attend_pos, layer=layer,
                                 window=cfg.window, use_pallas=use_pallas,
                                 interpret=interpret)
     out = out.reshape(b, 1, cfg.num_heads, cfg.head_dim).astype(x.dtype)

@@ -93,9 +93,12 @@ def make_prefill_step(cfg: LMConfig, max_len):
 #: The host loop's counters (:meth:`ServeEngine.counters`): decode ticks,
 #: active rows summed over them, admission groups, requests admitted, the
 #: padded rows those groups prefilled (slots x bucket) and the prompt
-#: tokens among them that were not served from the prefix cache.
+#: tokens among them that were not served from the prefix cache; on the
+#: paged engine, the KV pages the decode ticks' rows attended (a row whose
+#: token sees ``keys`` positions reads ``ceil(keys / page_size)`` pages
+#: of each layer's K and V), zero on the dense one.
 LOOP_COUNTERS = ("decode_ticks", "decode_rows", "admissions", "admitted",
-                 "admit_rows", "admit_tokens")
+                 "admit_rows", "admit_tokens", "decode_kv_pages")
 PREFIX_COUNTERS = ("hits", "misses", "evictions", "peak_pages",
                    "pages_in_use")
 
@@ -716,17 +719,21 @@ class ServeEngine:
                 emitted_np, done_np = jax.device_get((self.tokens, done))
             with _span("serve.emit"):
                 t_wall = time.perf_counter()
-                rows = 0
+                rows = pages = 0
                 for s in range(self.slots):
                     req = self._host_active[s]
                     if req is None:
                         continue
                     rows += 1
                     req.out.append(int(emitted_np[s, 0]))
+                    if self.paged:
+                        keys = len(req.prompt) + len(req.out) - 1
+                        pages += -(-keys // self.page_size)
                     if done_np[s]:
                         self._finish(s, now, t_wall, log)
             self._counts["decode_ticks"] += 1
             self._counts["decode_rows"] += rows
+            self._counts["decode_kv_pages"] += pages
         self._now = now + 1
         self.ticks = self._now
         return self._finished[n_done:]

@@ -272,6 +272,115 @@ def test_paged_decode_reads_the_given_layer_of_stacked_pools():
                                    rtol=3e-5, atol=3e-5)
 
 
+def _paged_case(key, b, n, g, d, ps, pp):
+    """q, single-layer pools with distinct pages for every slot (page 0
+    left to the trash) and the page table."""
+    ks = jax.random.split(jax.random.key(key), 4)
+    num_pages = b * pp + 1
+    q = jax.random.normal(ks[0], (b, n, g, d))
+    kp = jax.random.normal(ks[1], (1, num_pages, ps, n * d))
+    vp = jax.random.normal(ks[2], (1, num_pages, ps, n * d))
+    pt = (1 + jax.random.permutation(ks[3], b * pp)).reshape(b, pp)
+    return q, kp, vp, pt.astype(jnp.int32)
+
+
+def _small_blocks(monkeypatch, ppb, ps, width):
+    """Blocks of ``ppb`` (a power of two) f32 pages of (ps, width), so
+    that small tables span several blocks."""
+    from repro.kernels import swa_decode
+    monkeypatch.setattr(swa_decode, "_BLOCK_BYTES", ppb * ps * width * 4)
+
+
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("g", [1, 4])
+def test_paged_decode_reads_no_page_outside_a_rows_span(monkeypatch, g,
+                                                        window):
+    """Pages past each row's last position (and, with a window, before its
+    first) hold NaN: any read of one would show in the output."""
+    from repro.kernels.swa_decode import paged_decode
+    b, n, d, ps, pp = 4, 2, 32, 8, 7
+    _small_blocks(monkeypatch, 2, ps, n * d)
+    q, kp, vp, pt = _paged_case(11 + g, b, n, g, d, ps, pp)
+    pos = jnp.asarray([3, 17, 40, ps * pp - 1], jnp.int32)
+    dead = np.zeros(kp.shape[1], bool)
+    table, ends = jax.device_get((pt, pos))
+    for row, p in zip(table, ends):
+        first = 0 if window is None else max(p - window + 1, 0) // ps
+        dead[row[p // ps + 1:]] = True
+        dead[row[:first]] = True
+    poison = lambda a: jnp.where(dead[None, :, None, None], jnp.nan, a)
+    got = paged_decode(q, poison(kp), poison(vp), pt, pos, window=window,
+                       interpret=True)
+    want = ref.paged_decode_ref(q, kp[0], vp[0], pt, pos, window=window)
+    np.testing.assert_allclose(got, want, rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("g", [1, 4])
+def test_paged_decode_inactive_rows_return_zeros(monkeypatch, g):
+    """A row at ``pos = -1`` reads nothing and returns zeros, and the
+    active rows beside it (before, between and after) are untouched."""
+    from repro.kernels.swa_decode import paged_decode
+    b, n, d, ps, pp = 5, 2, 32, 8, 4
+    _small_blocks(monkeypatch, 2, ps, n * d)
+    q, kp, vp, pt = _paged_case(21 + g, b, n, g, d, ps, pp)
+    pos = jnp.asarray([-1, 12, -1, -1, 31], jnp.int32)
+    got = paged_decode(q, kp, vp, pt, pos, interpret=True)
+    np.testing.assert_array_equal(got[jnp.asarray([0, 2, 3])], 0.0)
+    want = ref.paged_decode_ref(q, kp[0], vp[0], pt, pos)
+    np.testing.assert_allclose(got, want, rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("ppb", [2, 4])
+@pytest.mark.parametrize("k", [1, 3, 4])
+def test_paged_decode_block_edges(monkeypatch, ppb, k):
+    """A table of 7 pages in blocks of 2 or 4 (never a multiple), with rows
+    at the last key of page k-1 and the first of page k: a row's last
+    block may be full or hold one page.  A position past the table attends
+    the whole table, as the reference does, and copies no page beyond it."""
+    from repro.kernels.swa_decode import paged_decode
+    b, n, g, d, ps, pp = 5, 2, 2, 32, 8, 7
+    _small_blocks(monkeypatch, ppb, ps, n * d)
+    q, kp, vp, pt = _paged_case(31 + k, b, n, g, d, ps, pp)
+    pos = jnp.asarray([k * ps - 1, k * ps, ps * pp - 1, 0, ps * pp + 5],
+                      jnp.int32)
+    got = paged_decode(q, kp, vp, pt, pos, interpret=True)
+    want = ref.paged_decode_ref(q, kp[0], vp[0], pt, pos)
+    np.testing.assert_allclose(got, want, rtol=3e-5, atol=3e-5)
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_paged_decode_double_buffer_has_no_races(monkeypatch, g):
+    """The race detector of the TPU interpreter over the hand-made double
+    buffer: copies started a block (and a row) ahead, copies waited, the
+    buffer half read between them; rows of one, two and three blocks, an
+    inactive row between them."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.swa_decode import paged_decode
+    b, n, d, ps, pp = 4, 2, 32, 8, 6
+    _small_blocks(monkeypatch, 2, ps, n * d)
+    q, kp, vp, pt = _paged_case(41 + g, b, n, g, d, ps, pp)
+    pos = jnp.asarray([20, -1, 5, 47], jnp.int32)
+    got = paged_decode(q, kp, vp, pt, pos, interpret=pltpu.InterpretParams(
+        detect_races=True, dma_execution_mode="eager"))
+    assert not ipc.races.races_found
+    want = ref.paged_decode_ref(q, kp[0], vp[0], pt, pos)
+    np.testing.assert_allclose(got, want, rtol=3e-5, atol=3e-5)
+
+
+def test_paged_decode_ref_inactive_rows_are_zeros():
+    """The reference follows the kernel's rule: ``pos < 0`` attends
+    nothing and gives zeros; other rows are as before."""
+    q, kp, vp, pt = _paged_case(51, 3, 2, 2, 16, 8, 3)
+    pos = jnp.asarray([9, -1, 23], jnp.int32)
+    got = ref.paged_decode_ref(q, kp[0], vp[0], pt, pos)
+    np.testing.assert_array_equal(got[1], 0.0)
+    for i in (0, 2):
+        one = ref.paged_decode_ref(q[i:i + 1], kp[0], vp[0], pt[i:i + 1],
+                                   pos[i:i + 1])
+        np.testing.assert_array_equal(got[i:i + 1], one)
+
+
 # -- dequant-fused KD loss (transport subsystem) ------------------------------
 
 

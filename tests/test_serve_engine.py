@@ -607,7 +607,8 @@ def test_vectorized_pos_decode_matches_scalar(ring):
 def test_counters_and_stamps_account_for_every_request(paged):
     """The engine's counters add up to what its requests record: every
     request admitted once, its suffix tokens prefilled, one decode row per
-    token after the first; ``t_enqueue <= t_admit <= t_first <= t_done``.
+    token after the first, on the paged engine the pages those rows
+    attended; ``t_enqueue <= t_admit <= t_first <= t_done``.
     ``prefix_stats`` is the prefix part of ``counters``; ``reset`` zeroes
     them."""
     cfg, params, mesh = _setup()
@@ -626,6 +627,14 @@ def test_counters_and_stamps_account_for_every_request(paged):
     assert c["admit_tokens"] <= c["admit_rows"]
     assert c["decode_rows"] == sum(len(r.out) - 1 for r in finished)
     assert c["decode_rows"] <= c["decode_ticks"] * engine.slots
+    # The decode token that makes out m long attends len(prompt) + m - 1
+    # keys: ceil of that over the page size pages of K and of V a layer.
+    pages = sum(-(-(len(r.prompt) + m - 1) // ps)
+                for r in finished for m in range(2, len(r.out) + 1))
+    assert c["decode_kv_pages"] == (pages if paged else 0)
+    if paged:
+        assert c["decode_kv_pages"] <= (c["decode_ticks"] * engine.slots
+                                        * engine.pages_per_slot)
     assert c["decode_ticks"] <= engine.ticks
     for r in finished:
         assert 0 < r.t_enqueue <= r.t_admit <= r.t_first <= r.t_done
