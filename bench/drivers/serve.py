@@ -25,7 +25,8 @@ import time
 
 import numpy as np
 
-from bench import counts, harness, program, reference, traffic
+from bench import counts, harness, program, program_trace, readers, \
+    reference, traffic
 from bench.weights import Dims, make_weights
 
 TAG_WEIGHTS = 1
@@ -175,10 +176,6 @@ def serve_window(engine, plan, seconds, tracer, counter):
     return ledger, t0, t_end, reqs[:handed], finished, inflight
 
 
-def percentile(xs, q):
-    return float(np.percentile(np.asarray(xs, np.float64), q))
-
-
 def end_to_end(ledger, plan, reqs, t0, t_end, setup_s):
     due = {p.rid: t0 + p.due for p in plan}
     ttft = []
@@ -187,21 +184,22 @@ def end_to_end(ledger, plan, reqs, t0, t_end, setup_s):
         ttft.append(first - due[r.rid])
     gaps = [b - a for ts in ledger.times.values()
             for a, b in zip(ts, ts[1:])]
-    med = percentile(gaps, 50) if gaps else 0.0
+    med = readers.percentile(gaps, 50) if gaps else 0.0
     tokens = sum(len(ts) for ts in ledger.times.values())
     window_s = t_end - t0
     out = {"setup_s": setup_s, "decode_tok_s": tokens / window_s}
     if ttft:
-        out["ttft_p90_ms"] = 1e3 * percentile(ttft, 90)
+        out["ttft_p90_ms"] = 1e3 * readers.percentile(ttft, 90)
     if gaps:
-        out["itl_p99_ms"] = 1e3 * percentile(gaps, 99)
+        out["itl_p99_ms"] = 1e3 * readers.percentile(gaps, 99)
+    pct = readers.percentile
     return out, {"requests_due": len(reqs), "tokens": tokens,
                  "handed_late_max_ms": 1e3 * max(ledger.late, default=0.0),
                  "token_gaps": len(gaps), "window_s": window_s,
-                 "ttft_p50_ms": 1e3 * percentile(ttft, 50) if ttft else None,
+                 "ttft_p50_ms": 1e3 * pct(ttft, 50) if ttft else None,
                  "itl_p50_ms": 1e3 * med,
-                 "itl_p90_ms": 1e3 * percentile(gaps, 90) if gaps else None,
-                 "itl_p95_ms": 1e3 * percentile(gaps, 95) if gaps else None,
+                 "itl_p90_ms": 1e3 * pct(gaps, 90) if gaps else None,
+                 "itl_p95_ms": 1e3 * pct(gaps, 95) if gaps else None,
                  # gaps over 1.5 ticks: those that span an admission
                  "stalled_gap_share": (sum(g > 1.5 * med for g in gaps)
                                        / len(gaps)) if gaps else None}
@@ -299,19 +297,31 @@ def run(cell: harness.Cell, t_start):
     stats0 = engine.prefix_stats()
     setup_s = time.perf_counter() - t_start
 
+    before = engine.counters()
     ledger, t0, t_end, handed, finished, inflight = serve_window(
         engine, plan, cell.seconds, tracer, counter)
+    after = engine.counters()
     e2e, info = end_to_end(ledger, plan, handed, t0, t_end, setup_s)
+    due = {p.rid: t0 + p.due for p in plan}
+    facts = {"prompt_tokens": ledger.prompt_tokens,
+             "prefix_tokens": ledger.prefix_tokens,
+             "window_counters": (before, after), "slots": engine.slots,
+             "queue_waits_s": program_trace.queue_waits(handed, due, t_end)}
     stats = engine.prefix_stats()
     print(f"[serve] {info}; prefix cache {stats} (after set-up {stats0}); "
           f"prefix-hit share of the window's prompt tokens "
-          f"{ledger.prefix_tokens}/{ledger.prompt_tokens}", flush=True)
+          f"{ledger.prefix_tokens}/{ledger.prompt_tokens}; window decode "
+          f"rows/ticks {after['decode_rows'] - before['decode_rows']}/"
+          f"{after['decode_ticks'] - before['decode_ticks']} on "
+          f"{engine.slots} slots; queue waits {len(facts['queue_waits_s'])}, "
+          f"p90 {readers.percentile(facts['queue_waits_s'], 90)} s",
+          flush=True)
+    t_drain = time.perf_counter()
     drain(engine, inflight, finished, mix["check"]["drain_s"])
-    mem = harness.memory_peak_bytes(cell.workload["chips"])
-
-    facts = {"memory_peak_bytes": mem,
-             "prompt_tokens": ledger.prompt_tokens,
-             "prefix_tokens": ledger.prefix_tokens}
+    print(f"[drain] {time.perf_counter() - t_drain:.3f} s, requests left "
+          f"in flight: {engine.pending()}", flush=True)
+    facts["memory_peak_bytes"] = harness.memory_peak_bytes(
+        cell.workload["chips"])
     breakdown = None
     if cell.trace:
         tr = tracer.load()
@@ -321,12 +331,14 @@ def run(cell: harness.Cell, t_start):
     checked = sample(finished, mix["check"], cell.seed, ledger)
     del engine
     gc.collect()
+    t_ref = time.perf_counter()
     w = make_weights(dm, cell.seed, TAG_WEIGHTS)
     gaps = logit_gaps(dm, w, checked, mix["engine"]["max_len"])
     served = sum(len(r.out) for r in checked)
     most = max(map(len, ledger.holders), default=0)
     print(f"[check] {len(checked)} requests ({most} slots held at the "
-          f"busiest ticks), {served} served tokens, widest gap per request "
+          f"busiest ticks), {served} served tokens, reference "
+          f"{time.perf_counter() - t_ref:.1f} s, widest gap per request "
           f"{gaps}", flush=True)
     return harness.Outcome(e2e=e2e, facts=facts, attempted=len(handed),
                            failed=0, checks=checks(gaps, mix),

@@ -5,12 +5,16 @@ engine keeps up, by a sweep of rates in one process on the chip.
         --seconds 30 --seed 5
 
 For each rate the cell's mix is run with that rate for ``--seconds`` on one
-engine (weights and warm-up made once), drained between rates, and one
-line is printed: requests due and admitted, time to first token at p50
-and p90, and how much the wait grew from the first third of the window's
-requests to the last.  A rate is sustained where every request due was
-admitted and the wait does not grow.  The result is written into the mix
-file and PERF.md by hand; the benchmark's own runs never sweep.
+engine (weights made once), reset before each rate so that its page pool
+and prefix cache start as a benchmark run's do, and one line is printed:
+requests due and admitted, time to first token at p50 and p90, how much
+the wait grew from the first third of the window's requests to the last,
+slot occupancy, queue wait and the prefix cache's counters.  A rate is
+sustained where every request due was admitted and the wait does not
+grow; a rate whose window fails (something compiled in it) prints the
+reason and the prefix counters, and the sweep goes on.  The result is
+written into the mix file and PERF.md by hand; the benchmark's own runs
+never sweep.
 """
 
 import argparse
@@ -22,7 +26,7 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
-from bench import harness, traffic  # noqa: E402
+from bench import harness, program_trace, readers, traffic  # noqa: E402
 from bench.drivers import serve  # noqa: E402
 from bench.weights import Dims  # noqa: E402
 
@@ -53,9 +57,17 @@ def main(argv=None):
         mix = copy.deepcopy(base)
         mix["arrivals"]["rate_per_s"] = rate
         plan = traffic.plan(mix, a.seed, a.seconds, dm.vocab)
+        engine.reset()
         serve.warm(engine, plan, mix, a.seed, dm.vocab)
-        ledger, t0, t_end, handed, finished, inflight = serve.serve_window(
-            engine, plan, a.seconds, tracer, counter)
+        before = engine.counters()
+        try:
+            ledger, t0, t_end, handed, finished, inflight = \
+                serve.serve_window(engine, plan, a.seconds, tracer, counter)
+        except harness.HarnessError as e:       # not sustained; go on
+            print(json.dumps({"rate_per_s": rate, "failed": str(e),
+                              "prefix": engine.prefix_stats()}), flush=True)
+            continue
+        after = engine.counters()
         e2e, info = serve.end_to_end(ledger, plan, handed, t0, t_end, 0.0)
         due = {p.rid: t0 + p.due for p in plan}
         waits = [(r.t_first if 0 < r.t_first <= t_end else t_end)
@@ -67,9 +79,15 @@ def main(argv=None):
             "ttft_p50_ms": info["ttft_p50_ms"],
             "ttft_p90_ms": e2e.get("ttft_p90_ms"),
             "itl_p99_ms": e2e.get("itl_p99_ms"),
+            "stalled_gap_share": info["stalled_gap_share"],
             "wait_first_third_s": sum(waits[:third]) / third,
             "wait_last_third_s": sum(waits[-third:]) / third,
-            "tokens_per_s": e2e["decode_tok_s"]}), flush=True)
+            "tokens_per_s": e2e["decode_tok_s"],
+            "slot_occupancy": program_trace.slot_occupancy(
+                before, after, engine.slots),
+            "queue_wait_p90_s": readers.percentile(
+                program_trace.queue_waits(handed, due, t_end), 90),
+            "prefix": engine.prefix_stats()}), flush=True)
         serve.drain(engine, inflight, finished, 600)
 
 

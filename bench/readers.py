@@ -8,6 +8,8 @@ want of data.
 
 from __future__ import annotations
 
+import numpy as np
+
 
 def ratio(facts, num, den):
     """``facts[num] / facts[den]`` in %, or None."""
@@ -44,3 +46,10 @@ def mfu(facts, flops_key, seconds_key, runs_key=None):
 def roofline(facts, least_key, seconds_key):
     """The kernel's least time over its measured time, in %."""
     return ratio(facts, least_key, seconds_key)
+
+
+def percentile(xs, q):
+    """The ``q``-th percentile of ``xs``, or None where ``xs`` is empty."""
+    if not len(xs):
+        return None
+    return float(np.percentile(np.asarray(xs, np.float64), q))

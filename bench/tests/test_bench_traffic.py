@@ -2,7 +2,9 @@
 work, and faithful to the mix files."""
 
 import json
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -85,3 +87,20 @@ def test_lengths_at_stratified_quantiles():
 def test_zipf_counts():
     c = traffic.zipf_counts(8, 100, 1.1)
     assert sum(c) == 100 and c == sorted(c, reverse=True) and c[0] > 2 * c[2]
+
+
+def test_the_chat_mix_runs_at_its_stated_share_of_the_knee():
+    """The chat cell's rate is the factor its source states times the rate
+    recorded there (the knee, or the highest rate swept where the sweep
+    found none), rounded down to 0.1 req/s, and a window of the
+    benchmark's length holds enough requests for its tails."""
+    from bench.tests.conftest import REAL
+    m = load("chat-sysprompt")
+    said = re.match(r"Poisson at ([0-9.]+) x ([0-9.]+) req/s",
+                    m["sources"]["arrivals"])
+    factor, knee = map(float, said.groups())
+    assert 0.7 <= factor <= 0.85
+    assert m["arrivals"]["rate_per_s"] == math.floor(
+        factor * knee * 10 + 1e-9) / 10
+    plan = traffic.plan(m, 2**31 + 11, REAL["run_seconds"], 1000)
+    assert len(plan) >= 100
